@@ -14,6 +14,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 MODULES = [
     "fig04_virt_overhead",
     "fig05_api_times",
@@ -33,6 +35,7 @@ MODULES = [
 
 def main() -> None:
     wanted = sys.argv[1:]
+    enable_compile_cache()
     mods = [m for m in MODULES
             if not wanted or any(w in m for w in wanted)]
     print("name,us_per_call,derived")

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import jax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.sharding.rules import ShardingRules
 
@@ -35,6 +36,7 @@ def reshard_snapshot_buffers(cfg, buffers: dict, new_mesh,
                              policy: str = "fsdp_tp") -> dict:
     """Reshard the checkpointed buffer dict; params/opt get param rules,
     other buffers are placed replicated (they are small or re-created)."""
+    replicated = NamedSharding(new_mesh, PartitionSpec())
     out = {}
     for buff_id, tree in buffers.items():
         if buff_id in ("params",):
@@ -50,10 +52,10 @@ def reshard_snapshot_buffers(cfg, buffers: dict, new_mesh,
                 out[buff_id] = {
                     "m": reshard_tree(tree["m"], sh),
                     "v": reshard_tree(tree["v"], sh),
-                    "count": jax.device_put(tree["count"]),
+                    "count": jax.device_put(tree["count"], replicated),
                 }
             else:
-                out[buff_id] = jax.device_put(tree)
+                out[buff_id] = jax.device_put(tree, replicated)
         else:
-            out[buff_id] = jax.device_put(tree)
+            out[buff_id] = jax.device_put(tree, replicated)
     return out

@@ -1,7 +1,7 @@
 """stablelm-3b [dense] — MHA, partial rotary embeddings.
 
 32L d_model=2560 32H (GQA kv=32) d_ff=6912 vocab=50304
-[hf:stabilityai/stablelm-2-1_6b; unverified]
+[hf:stabilityai/stablelm-3b-4e1t]
 """
 
 from repro.configs.base import ModelConfig
@@ -20,5 +20,5 @@ CONFIG = ModelConfig(
     norm_kind="layernorm",
     rope_pct=0.25,              # stablelm-family partial rotary
     rope_theta=10_000.0,
-    source="hf:stabilityai/stablelm-2-1_6b; unverified",
+    source="hf:stabilityai/stablelm-3b-4e1t",
 )

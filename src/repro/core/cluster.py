@@ -2,9 +2,11 @@
 
 ``make_cluster`` builds N worker nodes — each with a vSlice allocator, a
 Funky runtime daemon, a container engine and a node agent — plus the leader's
-orchestrator.  On this CPU host every vSlice maps to the same physical
-device (as multiple vFPGAs map onto one card's slots); isolation and
-accounting are enforced by the monitors.
+orchestrator.  Node *i* owns ``jax.devices()[i % n_devices]``: on a
+four-chip host nodes 0-3 each own a chip, and a migration between them moves
+the task's buffers across chips; with one device every node shares it (as
+several vFPGAs share one card's slots), and isolation and accounting are
+enforced by the monitors.
 """
 
 from __future__ import annotations
@@ -13,6 +15,8 @@ import os
 import tempfile
 from dataclasses import dataclass
 from typing import Dict, Optional
+
+import jax
 
 from repro.core.cri import ContainerEngine
 from repro.core.node_agent import NodeAgent
@@ -66,8 +70,11 @@ def make_cluster(num_nodes: int = 3, slices_per_node: int = 1,
     synthetic failure domains (rack/PDU model) for replica anti-affinity;
     the default gives every node its own domain.  ``chaos`` (a
     ``repro.chaos.FaultPlan``) is threaded into every runtime, monitor
-    and node agent for deterministic fault injection."""
+    and node agent for deterministic fault injection.  ``mem_cap_bytes`` is
+    each vSlice's device-memory budget; on a chip pass the device's
+    ``memory_stats()["bytes_limit"]``."""
     images = images or {}
+    devices = jax.devices()
     ckpt_root = ckpt_root or tempfile.mkdtemp(prefix="funky-ckpt-")
     metrics = metrics if metrics is not None else MetricsRegistry()
     engines: Dict[str, ContainerEngine] = {}
@@ -75,7 +82,8 @@ def make_cluster(num_nodes: int = 3, slices_per_node: int = 1,
     for i in range(num_nodes):
         nid = f"node{i}"
         alloc = SliceAllocator(nid, slices_per_node,
-                               mem_cap_bytes=mem_cap_bytes)
+                               mem_cap_bytes=mem_cap_bytes,
+                               device=devices[i % len(devices)])
         rt = FunkyRuntime(nid, alloc,
                           ckpt_root=os.path.join(ckpt_root, nid),
                           telemetry=metrics, chaos=chaos)

@@ -131,7 +131,7 @@ class Monitor:
         self.vslice = vs
         self.programs.register(program)
         entry = self.programs.get_or_compile(
-            program.program_id, abstract_args, donate_argnums)
+            program.program_id, abstract_args, donate_argnums, vs.device)
         self.metrics["reconfig_seconds"] += time.perf_counter() - t0
         self.metrics_hist["reconfig"].append(entry.compile_seconds)
         self._spawn_worker()
@@ -143,7 +143,7 @@ class Monitor:
         """Additional programs on the already-acquired slice."""
         self.programs.register(program)
         self.programs.get_or_compile(program.program_id, abstract_args,
-                                     donate_argnums)
+                                     donate_argnums, self.vslice.device)
 
     def vfpga_exit(self):
         """Release the slot; zero device memory (paper: isolation, §3.4)."""
@@ -296,7 +296,7 @@ class Monitor:
         t0 = time.perf_counter()
         if req.direction is Direction.H2D:
             nbytes = tree_bytes(req.host_value)
-            dev = jax.device_put(req.host_value)
+            dev = jax.device_put(req.host_value, self.vslice.device)
             self.buffers.on_h2d(req.buff_id, req.host_value, dev)
             self._tel_h2d_bytes.inc(nbytes)
             out = None
@@ -354,7 +354,8 @@ class Monitor:
                     i for i, b in enumerate(req.in_buffs)
                     if b in req.out_buffs)
             entry = self.programs.get_or_compile(req.program_id, abstract,
-                                                 donate_argnums)
+                                                 donate_argnums,
+                                                 self.vslice.device)
         args = tuple(self.buffers.get(i).device_value for i in req.in_buffs)
         args = args + tuple(req.const_args)
         # device phase: the compiled-program call is the only point this
@@ -469,7 +470,9 @@ class Monitor:
                 raise NoSliceAvailable(
                     f"no free vSlice on node {self.allocator.node_id}")
             self.vslice = vs
-            stats = self.buffers.restore_device_state()
+            stats = self.buffers.restore_device_state(vs.device)
+            # cached signatures were compiled for the previous slice's device
+            self._exec_cache.clear()
             self._spawn_worker()
             self.state = MonitorState.RUNNING
             stats["resume_seconds"] = time.perf_counter() - t0

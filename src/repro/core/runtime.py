@@ -249,6 +249,8 @@ class FunkyRuntime:
         if source is not None and source is not self:
             rec = source.migrate_out(cid)
             rec.monitor.allocator = self.allocator
+            # programs compile into this node's cache, for this node's device
+            rec.monitor.programs = self.programs
             with self._lock:
                 self.tasks[cid] = rec
         rec = self.tasks[cid]

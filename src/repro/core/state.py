@@ -262,13 +262,12 @@ class BufferTable:
                 "n_dirty": n_dirty, "paged_saved_pages": paged_saved,
                 "paged_total_pages": paged_total}
 
-    def restore_device_state(self, put_fn=None) -> dict:
-        """Re-materialize device buffers from host copies."""
-        put = put_fn or jax.device_put
+    def restore_device_state(self, device) -> dict:
+        """Re-materialize device buffers from host copies onto ``device``."""
         restored = 0
         for b in self._buffers.values():
             if b.host_value is not None:
-                b.device_value = put(b.host_value)
+                b.device_value = jax.device_put(b.host_value, device)
                 b.state = BufferState.SYNC
                 if b.paged:
                     b.page_dirty = set()    # device mirrors the host copy

@@ -324,6 +324,10 @@ class EngineServeTask(GuestTask):
         from repro.scaling.serving import get_router
         from repro.serve.engine import ContinuousBatchingEngine, SpecConfig
 
+        if restore and self._engine is not None:
+            # migrated with its context: in-flight lanes resume mid-decode
+            self._engine.reattach(cl)
+            return
         im = self.image
         self._router = get_router(im.name,
                                   registry=cl._monitor.telemetry)

@@ -1,7 +1,9 @@
 """vSlice: the virtualized accelerator slot (paper's vFPGA).
 
-A vSlice is a lease on a logical sub-mesh of a node's devices with a memory
-budget (the Alveo U50's 8 GiB HBM maps to ``mem_cap_bytes``).  The node-local
+A vSlice is a lease on one accelerator device with a memory budget
+(``mem_cap_bytes``; on a chip, callers take it from the device's
+``memory_stats()["bytes_limit"]``).  Every upload, restore and compiled
+program of the task holding the slice targets ``device``.  The node-local
 ``SliceAllocator`` implements the two hypercalls:
 
     vfpga_init(task)  -> acquire a free slot (+ program "reconfiguration")
@@ -11,17 +13,17 @@ budget (the Alveo U50's 8 GiB HBM maps to ``mem_cap_bytes``).  The node-local
 from __future__ import annotations
 
 import threading
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Optional
 
-from repro.launch.mesh import compat_make_mesh
+import jax
 
 
 @dataclass
 class VSlice:
     node_id: str
     slice_id: int
-    mesh: object                        # jax Mesh (1-device mesh on CPU hosts)
+    device: object                      # the jax Device this slice leases
     mem_cap_bytes: int
     owner: Optional[str] = None         # task id
     configured_program: Optional[str] = None   # "bitstream" currently loaded
@@ -32,16 +34,17 @@ class VSlice:
 
 
 class SliceAllocator:
-    """Per-node vSlice pool."""
+    """Per-node vSlice pool; every slice of a node leases the node's device
+    (slots of one card share it, as vFPGAs share one FPGA)."""
 
     def __init__(self, node_id: str, num_slices: int,
-                 mem_cap_bytes: int = 8 << 30, mesh=None):
-        if mesh is None:
-            mesh = compat_make_mesh((1, 1), ("data", "model"))
+                 mem_cap_bytes: int = 8 << 30, device=None):
+        if device is None:
+            device = jax.devices()[0]
         self._lock = threading.Lock()
         self.node_id = node_id
         self.slices = [
-            VSlice(node_id=node_id, slice_id=i, mesh=mesh,
+            VSlice(node_id=node_id, slice_id=i, device=device,
                    mem_cap_bytes=mem_cap_bytes)
             for i in range(num_slices)
         ]

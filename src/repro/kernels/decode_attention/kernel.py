@@ -13,8 +13,8 @@ Design:
     keep = kv_pos <= pos (and window).
   * online softmax in VMEM scratch across kv blocks, f32 accumulation.
 
-This kernel is memory-bound by design (reads the whole cache once); the
-roofline analysis in EXPERIMENTS.md treats it as the HBM-bandwidth term.
+This kernel is memory-bound by design: it reads the whole cache once, in
+the head-major layout its wrapper transposes the token-major cache to.
 """
 
 from __future__ import annotations
@@ -25,10 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# older JAX spells pltpu.CompilerParams 'TPUCompilerParams'
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 NEG_INF = -1e30
 
@@ -47,8 +43,8 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, kvp_ref, o_ref,
 
     pos = pos_ref[0]
     q = q_ref[0, 0, :, :]                      # (G, hd)
-    k = k_ref[0, :, 0, :]                      # (BK, hd)
-    v = v_ref[0, :, 0, :]                      # (BK, hd)
+    k = k_ref[0, 0, :, :]                      # (BK, hd)
+    v = v_ref[0, 0, :, :]                      # (BK, hd)
     kvp = kvp_ref[0, :]                        # (BK,) int32
 
     s = jax.lax.dot_general(
@@ -91,6 +87,10 @@ def decode_attention_fwd(q, k, v, pos, kv_pos, *, window: int = 0,
     bk = min(bk, cap)
     assert cap % bk == 0, (cap, bk)
     qg = q.reshape(B, Hkv, G, hd)
+    # head-major cache: a (1, 1, BK, hd) block keeps the kv-head axis out of
+    # the last two block dims, which Mosaic tiles by (8, 128) or full extent
+    kt = k.transpose(0, 2, 1, 3)
+    vt = v.transpose(0, 2, 1, 3)
     kvp2 = kv_pos.reshape(1, cap).astype(jnp.int32)
     scale = hd ** -0.5
 
@@ -101,8 +101,8 @@ def decode_attention_fwd(q, k, v, pos, kv_pos, *, window: int = 0,
         grid=(B, Hkv, cap // bk),
         in_specs=[
             pl.BlockSpec((1, 1, G, hd), lambda b, h, kb, pos: (b, h, 0, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, kb, pos: (b, kb, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, kb, pos: (b, kb, h, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, kb, pos: (b, h, kb, 0)),
+            pl.BlockSpec((1, 1, bk, hd), lambda b, h, kb, pos: (b, h, kb, 0)),
             pl.BlockSpec((1, bk), lambda b, h, kb, pos: (0, kb)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
@@ -117,8 +117,8 @@ def decode_attention_fwd(q, k, v, pos, kv_pos, *, window: int = 0,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(jnp.asarray(pos, jnp.int32).reshape(1), qg, k, v, kvp2)
+    )(jnp.asarray(pos, jnp.int32).reshape(1), qg, kt, vt, kvp2)
     return out.reshape(B, 1, Hq, hd)

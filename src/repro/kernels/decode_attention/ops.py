@@ -2,20 +2,16 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.decode_attention.kernel import decode_attention_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def decode_attention(q, k, v, pos, *, kv_pos=None, window: int = 0,
-                     softcap: float = 0.0, bk: int = 512):
+                     softcap: float = 0.0, bk: int = 512,
+                     interpret: bool = False):
     if kv_pos is None:
         kv_pos = jnp.arange(k.shape[1], dtype=jnp.int32)
     return decode_attention_fwd(
         q, k, v, pos, kv_pos, window=window, softcap=softcap, bk=bk,
-        interpret=not _on_tpu())
+        interpret=interpret)

@@ -11,7 +11,8 @@ TPU-native design (not a CUDA port):
   * causal + sliding-window masking from block-local iotas; fully-masked kv
     blocks are skipped with ``pl.when`` (no MXU work issued).
 
-Validated against ``ref.sdpa_ref`` in interpret mode (tests/test_kernels_*).
+Validated against ``ref.sdpa_ref`` in interpret mode (tests/test_kernels.py)
+and compiled for v5e at model widths (tests/test_tpu_compile.py).
 """
 
 from __future__ import annotations
@@ -22,10 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# older JAX spells pltpu.CompilerParams 'TPUCompilerParams'
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 NEG_INF = -1e30
 
@@ -55,9 +52,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, :, 0, :]                     # (BQ, hd)
-        k = k_ref[0, :, 0, :]                     # (BK, hd)
-        v = v_ref[0, :, 0, :]                     # (BK, hd)
+        q = q_ref[0, 0, :, :]                     # (BQ, hd)
+        k = k_ref[0, 0, :, :]                     # (BK, hd)
+        v = v_ref[0, 0, :, :]                     # (BK, hd)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale   # (BQ, BK)
@@ -86,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(kb == nkb - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -110,26 +107,31 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
         _flash_kernel, bq=bq, bk=bk, causal=causal, window=window,
         softcap=softcap, scale=scale, kv_len=Skv)
 
-    return pl.pallas_call(
+    # head-major operands: (1, 1, BQ|BK, hd) blocks keep the head axis out
+    # of the last two block dims, which Mosaic tiles by (8, 128) or full
+    # extent
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, qb, kb: (b, qb, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qb, kb, g=group: (b, kb, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd),
-                         lambda b, h, qb, kb, g=group: (b, kb, h // g, 0)),
+            pl.BlockSpec((1, 1, bq, hd), lambda b, h, qb, kb: (b, h, qb, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qb, kb, g=group: (b, h // g, kb, 0)),
+            pl.BlockSpec((1, 1, bk, hd),
+                         lambda b, h, qb, kb, g=group: (b, h // g, kb, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, qb, kb: (b, qb, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, hd),
+                               lambda b, h, qb, kb: (b, h, qb, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(qt, kt, vt)
+    return out.transpose(0, 2, 1, 3)

@@ -26,10 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# older JAX spells pltpu.CompilerParams 'TPUCompilerParams'
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def _rglru_kernel(a_ref, b_ref, h_ref, hfin_ref, state_scr, *, bs: int):
     sb = pl.program_id(2)
@@ -39,12 +35,14 @@ def _rglru_kernel(a_ref, b_ref, h_ref, hfin_ref, state_scr, *, bs: int):
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    a = a_ref[0].astype(jnp.float32)          # (BS, BW)
-    b = b_ref[0].astype(jnp.float32)          # (BS, BW)
-
     def body(t, h):
-        h = a[t][None, :] * h + b[t][None, :]
-        h_ref[0, t, :] = h[0].astype(h_ref.dtype)
+        # one (1, BW) row per step, sliced on the refs: Mosaic has no
+        # dynamic_slice of a loaded value
+        row = pl.ds(t, 1)
+        a = a_ref[0, row, :].astype(jnp.float32)
+        b = b_ref[0, row, :].astype(jnp.float32)
+        h = a * h + b
+        h_ref[0, row, :] = h.astype(h_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, bs, body, state_scr[...])
@@ -82,7 +80,7 @@ def rglru_scan_fwd(a, b, *, bs: int = 256, bw: int = 512,
             jax.ShapeDtypeStruct((B, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

@@ -2,14 +2,9 @@
 
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.rglru_scan.kernel import rglru_scan_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def rglru_scan(a, b, *, bs: int = 256, bw: int = 512):
-    return rglru_scan_fwd(a, b, bs=bs, bw=bw, interpret=not _on_tpu())
+def rglru_scan(a, b, *, bs: int = 256, bw: int = 512,
+               interpret: bool = False):
+    return rglru_scan_fwd(a, b, bs=bs, bw=bw, interpret=interpret)

@@ -26,12 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# older JAX spells pltpu.CompilerParams 'TPUCompilerParams'
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
-
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
+def _ssd_kernel(a_ref, x_ref, dtc_ref, dtr_ref, b_ref, c_ref, y_ref, st_ref,
                 state_scr, *, cs: int):
     cb = pl.program_id(2)
     ncb = pl.num_programs(2)
@@ -40,19 +36,26 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
     def _init():
         state_scr[...] = jnp.zeros_like(state_scr)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (cs, P)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)[:, None]  # (cs, 1)
-    A = a_ref[0, 0]                                  # scalar (f32)
+    x = x_ref[0, 0].astype(jnp.float32)              # (cs, P)
+    dt = dtc_ref[0, 0].astype(jnp.float32)           # (cs, 1)
+    dt_row = dtr_ref[0, 0].astype(jnp.float32)       # (1, cs)
+    A = a_ref[pl.program_id(1)]                      # scalar (f32, SMEM)
     Bm = b_ref[0].astype(jnp.float32)                # (cs, N)
     Cm = c_ref[0].astype(jnp.float32)                # (cs, N)
 
     dA = dt * A                                      # (cs, 1), <= 0
-    dA_cs = jnp.cumsum(dA, axis=0)                   # (cs, 1)
-
-    # --- intra-chunk -----------------------------------------------------
-    diff = dA_cs - dA_cs.reshape(1, cs)              # (cs, cs)
+    dA_row = dt_row * A                              # (1, cs)
     ii = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (cs, cs), 1)
+    # inclusive prefix sums as masked reductions (Mosaic has no cumsum),
+    # once down each axis so neither needs a transpose
+    dA_cs = jnp.sum(jnp.where(jj <= ii, dA_row, 0.0), axis=1,
+                    keepdims=True)                   # (cs, 1)
+    dA_cs_row = jnp.sum(jnp.where(ii <= jj, dA, 0.0), axis=0,
+                        keepdims=True)               # (1, cs)
+
+    # --- intra-chunk -----------------------------------------------------
+    diff = dA_cs - dA_cs_row                         # (cs, cs)
     L = jnp.where(ii >= jj, jnp.exp(diff), 0.0)
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
@@ -69,10 +72,10 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
         c_scaled, state, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # (cs, P)
 
-    y_ref[0, :, 0, :] = (y_diag + y_off).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     # --- state update --------------------------------------------------------
-    last = dA_cs[cs - 1, 0]
+    last = jnp.sum(dA_row, axis=1, keepdims=True)    # (1, 1)
     decay_last = jnp.exp(last - dA_cs)               # (cs, 1)
     contrib = jax.lax.dot_general(
         xdt * decay_last, Bm, (((0,), (0,)), ((), ())),
@@ -96,30 +99,35 @@ def ssd_scan_fwd(x, dt, A, Bm, Cm, *, chunk: int = 256,
     cs = min(chunk, S)
     assert S % cs == 0, (S, cs)
     grid = (B, H, S // cs)
-    A2 = A.astype(jnp.float32).reshape(H, 1)
+    # head-major operands keep the head axis out of the last two block
+    # dims, which Mosaic tiles by (8, 128) or full extent; dt rides in both
+    # orientations so the kernel never transposes a vector
+    xt = x.transpose(0, 2, 1, 3)                     # (B, H, S, P)
+    dtt = dt.transpose(0, 2, 1)                      # (B, H, S)
 
     kernel = functools.partial(_ssd_kernel, cs=cs)
     y, st = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cs, 1, P), lambda b, h, cb: (b, cb, h, 0)),
-            pl.BlockSpec((1, cs, 1), lambda b, h, cb: (b, cb, h)),
-            pl.BlockSpec((1, 1), lambda b, h, cb: (h, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, 1, cs, P), lambda b, h, cb: (b, h, cb, 0)),
+            pl.BlockSpec((1, 1, cs, 1), lambda b, h, cb: (b, h, cb, 0)),
+            pl.BlockSpec((1, 1, 1, cs), lambda b, h, cb: (b, h, 0, cb)),
             pl.BlockSpec((1, cs, N), lambda b, h, cb: (b, cb, 0)),
             pl.BlockSpec((1, cs, N), lambda b, h, cb: (b, cb, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, cs, 1, P), lambda b, h, cb: (b, cb, h, 0)),
+            pl.BlockSpec((1, 1, cs, P), lambda b, h, cb: (b, h, cb, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, cb: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt, A2, Bm, Cm)
-    return y, st
+    )(A.astype(jnp.float32), xt, dtt[..., None], dtt[:, :, None, :], Bm, Cm)
+    return y.transpose(0, 2, 1, 3), st

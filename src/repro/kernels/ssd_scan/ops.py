@@ -2,15 +2,8 @@
 
 from __future__ import annotations
 
-import jax
-
 from repro.kernels.ssd_scan.kernel import ssd_scan_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256):
-    return ssd_scan_fwd(x, dt, A, Bm, Cm, chunk=chunk,
-                        interpret=not _on_tpu())
+def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, interpret: bool = False):
+    return ssd_scan_fwd(x, dt, A, Bm, Cm, chunk=chunk, interpret=interpret)

@@ -8,53 +8,18 @@ multi-pod: 2x16x16 = 512 chips with a leading "pod" data-parallel axis.
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(axis_shapes, axis_names, *, devices=None):
-    """Version-compat wrapper over ``jax.make_mesh``.
-
-    Newer JAX exposes ``jax.sharding.AxisType`` and ``make_mesh`` takes an
-    ``axis_types`` keyword; older releases (e.g. 0.4.x) have neither.  We
-    always want plain Auto axes, so request them explicitly where supported
-    and fall back to the default behaviour elsewhere.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names, devices=devices,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass  # make_mesh predates the axis_types keyword
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
-
-
-def compat_shard_map(body, *, mesh, in_specs, out_specs,
-                     check_vma: bool = False):
-    """Version-compat wrapper over ``jax.shard_map``.
-
-    Newer JAX exposes it at top level with a ``check_vma`` keyword; older
-    releases only have ``jax.experimental.shard_map.shard_map`` with the
-    same switch spelled ``check_rep``.
-    """
-    try:
-        sm = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-    return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_vma=check_vma)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(model: int = 1):
     """Debug mesh over however many local devices exist."""
     n = jax.device_count()
     assert n % model == 0, (n, model)
-    return compat_make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

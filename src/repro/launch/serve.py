@@ -10,6 +10,7 @@ import argparse
 import time
 
 from repro.core import TaskImage, TaskStatus, make_cluster
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=64)
     ap.add_argument("--tokens-per-step", type=int, default=8)
     args = ap.parse_args()
+    enable_compile_cache()
 
     steps = max(args.tokens // args.tokens_per_step, 1)
     image = TaskImage(

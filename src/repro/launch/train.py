@@ -19,6 +19,7 @@ import jax
 
 from repro.configs import get_arch
 from repro.core import TaskImage, TaskStatus, make_cluster
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train import (DataConfig, OptConfig, make_batch, make_train_state,
                          make_train_step)
 
@@ -83,6 +84,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--native", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     out = run_native(args) if args.native else run_funky(args)
     print(out)
 

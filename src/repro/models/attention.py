@@ -181,15 +181,19 @@ _INVALID_POS = jnp.int32(2**30)  # masked-out slot sentinel (kv_pos > q_pos)
 def _ring_cache_from_prefill(entries: dict, S: int, cap: int) -> dict:
     """Place prefill entries at ring slots ``pos % cap`` so subsequent decode
     writes (slot = pos % cap) evict oldest-first; unfilled slots get the
-    INVALID sentinel."""
+    INVALID sentinel.
+
+    The slots are static: the last ``n`` positions rotated by
+    ``(S - n) % cap``, then padded to ``cap``.  A roll and a pad, not a
+    scatter, which the TPU compiler rejects inside the layer scan."""
     n = min(S, cap)
-    pos = jnp.arange(S - n, S, dtype=jnp.int32)
-    idx = pos % cap
+    shift = (S - n) % cap
+    pos = jnp.roll(jnp.arange(S - n, S, dtype=jnp.int32), shift)
     out = {}
     for name, arr in entries.items():
-        buf = jnp.zeros((arr.shape[0], cap) + arr.shape[2:], arr.dtype)
-        out[name] = buf.at[:, idx].set(arr[:, S - n:])
-    out["kv_pos"] = jnp.full((cap,), _INVALID_POS, jnp.int32).at[idx].set(pos)
+        pad = [(0, 0), (0, cap - n)] + [(0, 0)] * (arr.ndim - 2)
+        out[name] = jnp.pad(jnp.roll(arr[:, S - n:], shift, axis=1), pad)
+    out["kv_pos"] = jnp.pad(pos, (0, cap - n), constant_values=_INVALID_POS)
     return out
 
 

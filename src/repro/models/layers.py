@@ -134,8 +134,9 @@ def shard_logits(logits: jax.Array, mesh, dp_axes) -> jax.Array:
     before the loss (a multi-GB f32 temp at 150k vocab); with it, the loss
     below reduces shard-locally + small all-reduces.
     """
-    if mesh is None or "model" not in getattr(mesh, "axis_names", ()):
-        return logits
+    if (mesh is None or mesh.size == 1
+            or "model" not in getattr(mesh, "axis_names", ())):
+        return logits       # nothing to shard on one device
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
@@ -160,8 +161,8 @@ def _axsize(mesh, axes) -> int:
 def shard_batch_dim(x: jax.Array, mesh, dp_axes) -> jax.Array:
     """Constrain dim0 (batch) over the data axes — anchors propagation so
     activations never silently replicate across data shards."""
-    if mesh is None or not dp_axes:
-        return x
+    if mesh is None or mesh.size == 1 or not dp_axes:
+        return x            # nothing to shard on one device
     if x.shape[0] % _axsize(mesh, dp_axes) != 0:
         return x
     from jax.sharding import NamedSharding

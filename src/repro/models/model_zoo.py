@@ -45,10 +45,11 @@ def _default_dp_axes(mesh) -> tuple[str, ...]:
 
 
 def _trivial_mesh():
-    from repro.launch.mesh import compat_make_mesh
-
-    n = jax.device_count()
-    return compat_make_mesh((1, n), ("data", "model"))
+    """A one-device abstract mesh: the expert ``shard_map`` binds to the
+    device its inputs live on, so a model built once serves any vSlice."""
+    return jax.sharding.AbstractMesh(
+        (1, 1), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def build_model(cfg: ModelConfig, *, mesh=None, impl: str = "naive",

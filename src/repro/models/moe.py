@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.launch.mesh import compat_shard_map
 from repro.models.layers import cdtype, init_mlp, mlp_fwd
 
 
@@ -226,7 +225,7 @@ def moe_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *, mesh,
             body = partial(_expert_shard_a2a, top_k=m.top_k,
                            num_experts=m.num_experts, capacity=capacity,
                            ep_axes=ep_axes)
-            out2d, aux = compat_shard_map(
+            out2d, aux = jax.shard_map(
                 body,
                 mesh=mesh,
                 in_specs=(P(tok_axes, None), P(None, None),
@@ -254,7 +253,7 @@ def moe_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *, mesh,
         _expert_shard, top_k=m.top_k, num_experts=m.num_experts,
         capacity=capacity, ep_axis=ep_axis, dp_axes=dp_axes)
 
-    out2d, aux = compat_shard_map(
+    out2d, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp_axes, None), P(None, None), P(ep_axis, None, None),

@@ -511,7 +511,9 @@ class ContinuousBatchingEngine:
         self.spec_accepted_drafts = 0
         self._mid_step = False              # pages in flight: no compaction
         self._setup_done = False
-        self._program_ids: List[str] = []
+        # (program, abstract args, donate_argnums) per registered program,
+        # replayed by ``reattach`` when the task lands on another slice
+        self._registrations: List[tuple] = []
 
     # ------------------------------------------------------------------
     # Program/buffer setup (Funky guest-style, via FunkyCL only)
@@ -524,12 +526,23 @@ class ContinuousBatchingEngine:
         self._setup_done = True
 
     def program_ids(self) -> tuple:
-        return tuple(self._program_ids)
+        return tuple(p.program_id for p, _, _ in self._registrations)
 
     def _register(self, cl, name, fn, abstracts, donate_argnums=()):
-        cl.clCreateProgramWithBinary(Program(name, fn), abstracts,
+        program = Program(name, fn)
+        cl.clCreateProgramWithBinary(program, abstracts,
                                      donate_argnums=donate_argnums)
-        self._program_ids.append(name)
+        self._registrations.append((program, abstracts, donate_argnums))
+
+    def reattach(self, cl: FunkyCL) -> None:
+        """Continue on a new vSlice after a migration: the monitor has
+        restored the device buffers onto the new slice's device and the
+        host lane state travelled with the task, so only the programs are
+        registered (compiled) again, for that device."""
+        self.cl = cl
+        for program, abstracts, donate_argnums in self._registrations:
+            cl.clCreateProgramWithBinary(program, abstracts,
+                                         donate_argnums=donate_argnums)
 
     def _prefill_fn(self):
         bundle = self.bundle
@@ -2021,6 +2034,16 @@ class ContinuousBatchingEngine:
         if self.paged:
             st.pos = st.bucket + len(st.tokens) - 1
 
+    def _owned_by_inflight(self, c) -> bool:
+        """True while an in-flight record will still wait on ``c`` at its
+        commit (its EXECUTE, its read, or a deferred admission's ops)."""
+        for rec in self._inflight:
+            owned = (rec[1], rec[2]) if rec[0] == "batch" else (rec[2],
+                                                               *rec[3])
+            if any(o is c for o in owned):
+                return True
+        return False
+
     def _fail_pipeline(self, records) -> None:
         """Drop every in-flight record after a failed EXECUTE: later
         pipelined EXECUTEs ran against the pre-failure state, so their
@@ -2326,6 +2349,12 @@ class ContinuousBatchingEngine:
             # failure already raised at a wait()/commit site.
             if c.error is not None:
                 if c.error_seen:
+                    continue
+                if self._owned_by_inflight(c):
+                    # its commit site rolls the pipeline back and raises
+                    # it; raising here too would surface it twice and
+                    # leave the failed span's advance in place
+                    carry.append(c)
                     continue
                 c.error_seen = True
                 raise c.error

@@ -3,14 +3,8 @@ shape sweeps."""
 
 import jax
 import jax.numpy as jnp
-import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:      # property tests skip; the rest of the module runs
-    HAS_HYPOTHESIS = False
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.models.attention import sdpa_blockwise, sdpa_naive
 
@@ -23,30 +17,26 @@ def _qkv(key, B, Sq, Skv, Hq, Hkv, hd, hd_v=None):
     return q, k, v
 
 
-if HAS_HYPOTHESIS:
-    @settings(max_examples=20, deadline=None)
-    @given(
-        B=st.integers(1, 3),
-        S=st.sampled_from([16, 32, 64]),
-        Hkv=st.sampled_from([1, 2, 4]),
-        G=st.sampled_from([1, 2, 4]),
-        hd=st.sampled_from([16, 32]),
-        causal=st.booleans(),
-        window=st.sampled_from([0, 8, 24]),
-        chunk=st.sampled_from([8, 16, 32]),
-    )
-    def test_blockwise_matches_naive(B, S, Hkv, G, hd, causal, window, chunk):
-        if window and not causal:
-            window = 0
-        q, k, v = _qkv(jax.random.PRNGKey(B * 1000 + S), B, S, S,
-                       Hkv * G, Hkv, hd)
-        ref = sdpa_naive(q, k, v, causal=causal, window=window)
-        out = sdpa_blockwise(q, k, v, causal=causal, window=window,
-                             chunk=chunk)
-        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
-else:
-    def test_blockwise_matches_naive():
-        pytest.importorskip("hypothesis")
+@settings(max_examples=20, deadline=None)
+@given(
+    B=st.integers(1, 3),
+    S=st.sampled_from([16, 32, 64]),
+    Hkv=st.sampled_from([1, 2, 4]),
+    G=st.sampled_from([1, 2, 4]),
+    hd=st.sampled_from([16, 32]),
+    causal=st.booleans(),
+    window=st.sampled_from([0, 8, 24]),
+    chunk=st.sampled_from([8, 16, 32]),
+)
+def test_blockwise_matches_naive(B, S, Hkv, G, hd, causal, window, chunk):
+    if window and not causal:
+        window = 0
+    q, k, v = _qkv(jax.random.PRNGKey(B * 1000 + S), B, S, S,
+                   Hkv * G, Hkv, hd)
+    ref = sdpa_naive(q, k, v, causal=causal, window=window)
+    out = sdpa_blockwise(q, k, v, causal=causal, window=window,
+                         chunk=chunk)
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
 def test_mla_head_dim_mismatch_supported():

@@ -184,13 +184,9 @@ def test_monitor_execute_retry_exhaustion_fails_request():
     assert "execute_failed" in kinds
 
 
-def test_pipelined_execute_error_surfaces_exactly_once(baseline_tokens):
-    """Regression for the step()-boundary drop: a pipelined fused EXECUTE
-    that errors *after* the step that submitted it must surface exactly
-    once (the old loop only raised for already-done completions, then
-    cleared the list — late failures were silently dropped and their
-    stale tokens committed).  After the raise the engine rolls the span
-    back, resubmits deterministically, and finishes bit-exactly."""
+def _surfaced_pipelined_failures(settle_before_sweep: bool):
+    """Serve the standard requests with the third fused EXECUTE failing;
+    returns (times it surfaced, completed streams, the fault plan)."""
     reg = MetricsRegistry()
     plan = FaultPlan([FaultSpec(site="monitor.execute", kind="error",
                                 at=3, max_fires=1, match="decode_multi")],
@@ -199,6 +195,17 @@ def test_pipelined_execute_error_surfaces_exactly_once(baseline_tokens):
     # retry loop would absorb it before it ever reached the engine
     mon, eng = _engine_factory(chaos=plan, registry=reg, retries=1,
                                fuse_steps=4, async_depth=1)
+    if settle_before_sweep:
+        fused = eng._fused_iteration
+
+        def fused_then_settle():
+            # every op this iteration queued completes before the sweep
+            out = fused()
+            for c in eng._step_completions:
+                c._event.wait(60)
+            return out
+
+        eng._fused_iteration = fused_then_settle
     for r in make_requests():
         eng.submit(r)
     raises = 0
@@ -212,6 +219,28 @@ def test_pipelined_execute_error_surfaces_exactly_once(baseline_tokens):
         assert guard < 10000, "engine did not drain"
     got = {rid: list(rec.tokens) for rid, rec in eng.completed.items()}
     mon.vfpga_exit()
+    return raises, got, plan
+
+
+def test_pipelined_execute_error_surfaces_exactly_once(baseline_tokens):
+    """Regression for the step()-boundary drop: a pipelined fused EXECUTE
+    that errors *after* the step that submitted it must surface exactly
+    once (the old loop only raised for already-done completions, then
+    cleared the list — late failures were silently dropped and their
+    stale tokens committed).  After the raise the engine rolls the span
+    back, resubmits deterministically, and finishes bit-exactly."""
+    raises, got, plan = _surfaced_pipelined_failures(False)
+    assert len(plan.fired) == 1
+    assert raises == 1, f"EXECUTE failure surfaced {raises} times, want 1"
+    assert got == baseline_tokens
+
+
+def test_pipelined_execute_error_done_before_boundary_surfaces_once(
+        baseline_tokens):
+    """The failed EXECUTE may finish before the step that submitted it
+    reaches its boundary sweep.  The sweep must leave it to the commit site
+    that owns it, which rolls the span back and raises it once."""
+    raises, got, plan = _surfaced_pipelined_failures(True)
     assert len(plan.fired) == 1
     assert raises == 1, f"EXECUTE failure surfaced {raises} times, want 1"
     assert got == baseline_tokens

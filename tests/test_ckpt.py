@@ -73,9 +73,8 @@ def test_reshard_params_roundtrip():
     b = build_model(cfg)
     params = b.init(jax.random.PRNGKey(0))
     host = jax.tree.map(lambda x: np.asarray(x), params)
-    from repro.launch.mesh import compat_make_mesh
-
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     new = reshard_params(cfg, host, mesh)
     for a, c in zip(jax.tree.leaves(new), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c))

@@ -99,7 +99,7 @@ def test_decode_and_admit_are_donated(engine_run):
     """The paged KV update path compiles with buffer donation (in-place
     pool update, no per-token copy of the pool)."""
     eng, _, _ = engine_run
-    mon_keys = [(pid, d) for (pid, _, d) in
+    mon_keys = [(pid, d) for (pid, _, d, _dev) in
                 eng.cl._monitor.programs._compiled.keys()]
     assert ("decode_step", (1, 2, 4)) in mon_keys          # toks, pos, pool
     assert (f"prefill_admit_{PROMPT_LEN}", (1, 2, 3)) in mon_keys
@@ -215,7 +215,7 @@ def test_prompt_buckets_route_admissions():
     assert eng._pick_bucket(4) == 4
     assert eng._pick_bucket(5) == 8
     assert eng._pick_bucket(99) == 8       # over-long prompts truncate
-    mon_keys = [pid for (pid, _, _) in
+    mon_keys = [pid for (pid, _, _, _dev) in
                 eng.cl._monitor.programs._compiled.keys()]
     assert {"prefill_admit_4", "prefill_admit_8"} <= set(mon_keys)
     rng = np.random.Generator(np.random.Philox(9))

@@ -4,13 +4,8 @@ sweeping shapes/dtypes as required by the assignment."""
 import jax
 import jax.numpy as jnp
 import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:      # property tests skip; the rest of the module runs
-    HAS_HYPOTHESIS = False
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.kernels.decode_attention.kernel import decode_attention_fwd
 from repro.kernels.decode_attention.ref import decode_ref
@@ -48,22 +43,18 @@ def test_flash_attention(B, S, Hq, Hkv, hd, causal, window, dtype):
     assert d < TOL[dtype], d
 
 
-if HAS_HYPOTHESIS:
-    @settings(max_examples=8, deadline=None)
-    @given(bq=st.sampled_from([32, 64, 128]),
-           bk=st.sampled_from([32, 64, 128]))
-    def test_flash_attention_block_shape_sweep(bq, bk):
-        ks = jax.random.split(jax.random.PRNGKey(11), 3)
-        q = jax.random.normal(ks[0], (1, 128, 4, 32), jnp.float32)
-        k = jax.random.normal(ks[1], (1, 128, 2, 32), jnp.float32)
-        v = jax.random.normal(ks[2], (1, 128, 2, 32), jnp.float32)
-        out = flash_attention_fwd(q, k, v, causal=True, bq=bq, bk=bk,
-                                  interpret=True)
-        ref = sdpa_ref(q, k, v, causal=True)
-        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
-else:
-    def test_flash_attention_block_shape_sweep():
-        pytest.importorskip("hypothesis")
+@settings(max_examples=8, deadline=None)
+@given(bq=st.sampled_from([32, 64, 128]),
+       bk=st.sampled_from([32, 64, 128]))
+def test_flash_attention_block_shape_sweep(bq, bk):
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (1, 128, 4, 32), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 128, 2, 32), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 128, 2, 32), jnp.float32)
+    out = flash_attention_fwd(q, k, v, causal=True, bq=bq, bk=bk,
+                              interpret=True)
+    ref = sdpa_ref(q, k, v, causal=True)
+    assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
 # ---------------------------------------------------------------------------
@@ -94,25 +85,21 @@ def test_decode_attention(B, cap, Hq, Hkv, hd, pos, window, dtype):
 # RG-LRU scan
 # ---------------------------------------------------------------------------
 
-if HAS_HYPOTHESIS:
-    @settings(max_examples=10, deadline=None)
-    @given(
-        B=st.integers(1, 3),
-        S=st.sampled_from([64, 128, 256]),
-        W=st.sampled_from([128, 256]),
-        bs=st.sampled_from([32, 64]),
-    )
-    def test_rglru_scan(B, S, W, bs):
-        ks = jax.random.split(jax.random.PRNGKey(S + W), 2)
-        a = jax.nn.sigmoid(jax.random.normal(ks[0], (B, S, W))) * 0.98
-        b = jax.random.normal(ks[1], (B, S, W)) * 0.1
-        h, hf = rglru_scan_fwd(a, b, bs=bs, bw=128, interpret=True)
-        rh, rhf = rglru_scan_ref(a, b)
-        assert float(jnp.max(jnp.abs(h - rh))) < 1e-4
-        assert float(jnp.max(jnp.abs(hf - rhf))) < 1e-4
-else:
-    def test_rglru_scan():
-        pytest.importorskip("hypothesis")
+@settings(max_examples=10, deadline=None)
+@given(
+    B=st.integers(1, 3),
+    S=st.sampled_from([64, 128, 256]),
+    W=st.sampled_from([128, 256]),
+    bs=st.sampled_from([32, 64]),
+)
+def test_rglru_scan(B, S, W, bs):
+    ks = jax.random.split(jax.random.PRNGKey(S + W), 2)
+    a = jax.nn.sigmoid(jax.random.normal(ks[0], (B, S, W))) * 0.98
+    b = jax.random.normal(ks[1], (B, S, W)) * 0.1
+    h, hf = rglru_scan_fwd(a, b, bs=bs, bw=128, interpret=True)
+    rh, rhf = rglru_scan_ref(a, b)
+    assert float(jnp.max(jnp.abs(h - rh))) < 1e-4
+    assert float(jnp.max(jnp.abs(hf - rhf))) < 1e-4
 
 
 # ---------------------------------------------------------------------------

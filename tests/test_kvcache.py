@@ -14,14 +14,10 @@ from repro.serve.kvcache import (BlockPool, BlockPoolError, cache_bytes,
                                  scatter_pages, scatter_prefill, scrub_pages,
                                  token_axes_from_lengths)
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    from hypothesis.stateful import (RuleBasedStateMachine, invariant,
-                                     precondition, rule)
-    HAS_HYPOTHESIS = True
-except ImportError:
-    HAS_HYPOTHESIS = False
+from hypothesis import given, settings
+from hypothesis import strategies as st
+from hypothesis.stateful import (RuleBasedStateMachine, invariant,
+                                 precondition, rule)
 
 
 # ---------------------------------------------------------------------------
@@ -131,122 +127,121 @@ def test_free_tail_releases_only_the_orphaned_suffix():
         pool.free_tail(blocks, 2)
 
 
-if HAS_HYPOTHESIS:
-    class PoolMachine(RuleBasedStateMachine):
-        """Random alloc/share/free/free_tail/compact sequences preserve the
-        partition invariant (free ∪ used = all pages, disjoint), ownership
-        (a live page is never re-allocated), and refcount semantics: a
-        page with references outstanding is never freed (so it can never
-        be scrubbed or handed to another owner), and compaction moves
-        reference counts with their pages."""
+class PoolMachine(RuleBasedStateMachine):
+    """Random alloc/share/free/free_tail/compact sequences preserve the
+    partition invariant (free ∪ used = all pages, disjoint), ownership
+    (a live page is never re-allocated), and refcount semantics: a
+    page with references outstanding is never freed (so it can never
+    be scrubbed or handed to another owner), and compaction moves
+    reference counts with their pages."""
 
-        def __init__(self):
-            super().__init__()
-            self.pool = BlockPool(16, 4, reserve_pages=2)
-            self.owned = {}             # owner -> ordered page list
-            self.rc = {}                # page -> model refcount
-            self.next_owner = 0
+    def __init__(self):
+        super().__init__()
+        self.pool = BlockPool(16, 4, reserve_pages=2)
+        self.owned = {}             # owner -> ordered page list
+        self.rc = {}                # page -> model refcount
+        self.next_owner = 0
 
-        def _drop_ref(self, p):
-            self.rc[p] -= 1
-            if self.rc[p] == 0:
-                del self.rc[p]
-                return True
-            return False
+    def _drop_ref(self, p):
+        self.rc[p] -= 1
+        if self.rc[p] == 0:
+            del self.rc[p]
+            return True
+        return False
 
-        @rule(n=st.integers(1, 5), urgent=st.booleans())
-        def alloc(self, n, urgent):
-            got = self.pool.alloc(n, urgent=urgent)
-            if got is not None:
-                assert not (set(got) & set(self.rc)), \
-                    "live page re-allocated"
-                self.owned[self.next_owner] = list(got)
-                for p in got:
-                    self.rc[p] = 1
-                self.next_owner += 1
+    @rule(n=st.integers(1, 5), urgent=st.booleans())
+    def alloc(self, n, urgent):
+        got = self.pool.alloc(n, urgent=urgent)
+        if got is not None:
+            assert not (set(got) & set(self.rc)), \
+                "live page re-allocated"
+            self.owned[self.next_owner] = list(got)
+            for p in got:
+                self.rc[p] = 1
+            self.next_owner += 1
 
-        @precondition(lambda self: self.rc)
-        @rule(data=st.data())
-        def share_one(self, data):
-            """A prefix-tree node (or second lane) pins a live page."""
-            p = data.draw(st.sampled_from(sorted(self.rc)))
-            self.pool.share([p])
-            self.rc[p] += 1
+    @precondition(lambda self: self.rc)
+    @rule(data=st.data())
+    def share_one(self, data):
+        """A prefix-tree node (or second lane) pins a live page."""
+        p = data.draw(st.sampled_from(sorted(self.rc)))
+        self.pool.share([p])
+        self.rc[p] += 1
 
-        @precondition(lambda self: any(c > 1 for c in self.rc.values()))
-        @rule(data=st.data())
-        def unshare_one(self, data):
-            """Dropping one of several references never frees the page."""
-            p = data.draw(st.sampled_from(
-                sorted(q for q, c in self.rc.items() if c > 1)))
-            assert self.pool.free([p]) == []
-            self._drop_ref(p)
+    @precondition(lambda self: any(c > 1 for c in self.rc.values()))
+    @rule(data=st.data())
+    def unshare_one(self, data):
+        """Dropping one of several references never frees the page."""
+        p = data.draw(st.sampled_from(
+            sorted(q for q, c in self.rc.items() if c > 1)))
+        assert self.pool.free([p]) == []
+        self._drop_ref(p)
 
-        @precondition(lambda self: self.owned)
-        @rule(data=st.data())
-        def free_owner(self, data):
-            """A retiring owner frees exactly its unshared pages."""
-            owner = data.draw(st.sampled_from(sorted(self.owned)))
-            pages = sorted(self.owned.pop(owner))
-            freed = self.pool.free(pages)
-            assert freed == [p for p in pages if self._drop_ref(p)]
+    @precondition(lambda self: self.owned)
+    @rule(data=st.data())
+    def free_owner(self, data):
+        """A retiring owner frees exactly its unshared pages."""
+        owner = data.draw(st.sampled_from(sorted(self.owned)))
+        pages = sorted(self.owned.pop(owner))
+        freed = self.pool.free(pages)
+        assert freed == [p for p in pages if self._drop_ref(p)]
 
-        @precondition(lambda self: self.owned)
-        @rule(data=st.data())
-        def rollback_tail(self, data):
-            """Speculative rollback: ``free_tail`` on a shared tail page
-            unshares it — the surviving owner keeps its copy."""
-            owner = data.draw(st.sampled_from(sorted(self.owned)))
-            blocks = self.owned[owner]
-            keep = data.draw(st.integers(0, len(blocks)))
-            freed = self.pool.free_tail(blocks, keep)
-            assert freed == [p for p in blocks[keep:]
-                             if self._drop_ref(p)]
-            self.owned[owner] = blocks[:keep]
-            if not self.owned[owner]:
-                del self.owned[owner]
+    @precondition(lambda self: self.owned)
+    @rule(data=st.data())
+    def rollback_tail(self, data):
+        """Speculative rollback: ``free_tail`` on a shared tail page
+        unshares it — the surviving owner keeps its copy."""
+        owner = data.draw(st.sampled_from(sorted(self.owned)))
+        blocks = self.owned[owner]
+        keep = data.draw(st.integers(0, len(blocks)))
+        freed = self.pool.free_tail(blocks, keep)
+        assert freed == [p for p in blocks[keep:]
+                         if self._drop_ref(p)]
+        self.owned[owner] = blocks[:keep]
+        if not self.owned[owner]:
+            del self.owned[owner]
 
-        @rule()
-        def compact(self):
-            mapping = self.pool.compact()
-            for owner, pages in self.owned.items():
-                self.owned[owner] = [mapping.get(p, p) for p in pages]
-            self.rc = {mapping.get(p, p): c for p, c in self.rc.items()}
+    @rule()
+    def compact(self):
+        mapping = self.pool.compact()
+        for owner, pages in self.owned.items():
+            self.owned[owner] = [mapping.get(p, p) for p in pages]
+        self.rc = {mapping.get(p, p): c for p, c in self.rc.items()}
 
-        @invariant()
-        def partition_holds(self):
-            self.pool.check_invariants()
-            assert set(self.rc) == self.pool._used
-            for p, c in self.rc.items():
-                assert self.pool.refcount(p) == c
-            assert self.pool.free_count() == 16 - len(self.rc)
+    @invariant()
+    def partition_holds(self):
+        self.pool.check_invariants()
+        assert set(self.rc) == self.pool._used
+        for p, c in self.rc.items():
+            assert self.pool.refcount(p) == c
+        assert self.pool.free_count() == 16 - len(self.rc)
 
-    TestPoolMachine = PoolMachine.TestCase
-    TestPoolMachine.settings = settings(max_examples=30,
-                                        deadline=None)
+TestPoolMachine = PoolMachine.TestCase
+TestPoolMachine.settings = settings(max_examples=30,
+                                    deadline=None)
 
-    @settings(max_examples=60, deadline=None)
-    @given(data=st.data())
-    def test_free_tail_property(data):
-        """Rollback frees exactly the orphaned tail: after interleaved
-        allocations, ``free_tail(blocks, keep)`` leaves precisely the kept
-        prefixes owned and the pool partition invariant intact."""
-        pool = BlockPool(16, 4, reserve_pages=2)
-        owners = []
-        for _ in range(data.draw(st.integers(1, 4))):
-            n = data.draw(st.integers(1, 4))
-            got = pool.alloc(n, urgent=True)
-            if got is not None:
-                owners.append(got)
-        kept = []
-        for blocks in owners:
-            keep = data.draw(st.integers(0, len(blocks)))
-            freed = pool.free_tail(blocks, keep)
-            assert freed == blocks[keep:]
-            kept.extend(blocks[:keep])
-        pool.check_invariants()
-        assert pool._used == set(kept)
-        assert pool.free_count() == 16 - len(kept)
+@settings(max_examples=60, deadline=None)
+@given(data=st.data())
+def test_free_tail_property(data):
+    """Rollback frees exactly the orphaned tail: after interleaved
+    allocations, ``free_tail(blocks, keep)`` leaves precisely the kept
+    prefixes owned and the pool partition invariant intact."""
+    pool = BlockPool(16, 4, reserve_pages=2)
+    owners = []
+    for _ in range(data.draw(st.integers(1, 4))):
+        n = data.draw(st.integers(1, 4))
+        got = pool.alloc(n, urgent=True)
+        if got is not None:
+            owners.append(got)
+    kept = []
+    for blocks in owners:
+        keep = data.draw(st.integers(0, len(blocks)))
+        freed = pool.free_tail(blocks, keep)
+        assert freed == blocks[keep:]
+        kept.extend(blocks[:keep])
+    pool.check_invariants()
+    assert pool._used == set(kept)
+    assert pool.free_count() == 16 - len(kept)
 
 
 # ---------------------------------------------------------------------------
@@ -377,7 +372,7 @@ def test_paged_buffer_evicts_only_dirty_pages():
     table.on_execute_write("pool", val)          # no dirty_pages: all dirty
     s1 = table.evict_device_state()
     assert s1["paged_saved_pages"] == 4 and s1["paged_total_pages"] == 4
-    table.restore_device_state()
+    table.restore_device_state(jax.devices()[0])
 
     new = jax.tree.map(lambda x: x + (x + 1) * 0, val)   # same values
     new["k"] = new["k"].at[2].set(-1.0)
@@ -401,7 +396,7 @@ def test_paged_buffer_degrades_without_page_info():
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), val), paged=True)
     table.on_execute_write("pool", val, dirty_pages=[0])
     table.evict_device_state()
-    table.restore_device_state()
+    table.restore_device_state(jax.devices()[0])
     table.on_execute_write("pool", val, stable=True)     # unknown pages
     s = table.evict_device_state()
     assert s["paged_saved_pages"] == 4                   # conservative

@@ -4,14 +4,16 @@ collective parser handles real and synthetic inputs."""
 
 import dataclasses
 
+import jax
+
 import pytest
 
 from repro.configs import SHAPES, get_arch
 from repro.launch.hlo_stats import collective_stats, collective_seconds
-from repro.launch.mesh import compat_make_mesh
 from repro.launch.steps import build_cell
 
-MESH = compat_make_mesh((1, 1), ("data", "model"))
+MESH = jax.make_mesh((1, 1), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 TINY = {
     "train": dataclasses.replace(SHAPES["train_4k"], seq_len=32,

@@ -204,7 +204,7 @@ def test_donated_execute_roundtrip():
     np.testing.assert_array_equal(np.asarray(cl.read_buffer("x")),
                                   np.full(8, 8.0, np.float32))
     # only the donate_argnums=(0,) variant was compiled (no double compile)
-    keys = [(pid, d) for (pid, _, d) in m.programs._compiled.keys()]
+    keys = [(pid, d) for (pid, _, d, _dev) in m.programs._compiled.keys()]
     assert keys.count(("double", (0,))) == 1
     assert ("double", ()) not in keys
     m.evict()

@@ -8,14 +8,9 @@ tasks across evict/resume/migrate, anti-affinity honored when feasible)."""
 import math
 
 import pytest
-
-try:
-    from hypothesis import settings
-    from hypothesis import strategies as st
-    from hypothesis.stateful import RuleBasedStateMachine, invariant, rule
-    HAS_HYPOTHESIS = True
-except ImportError:      # property tests skip; the rest of the module runs
-    HAS_HYPOTHESIS = False
+from hypothesis import settings
+from hypothesis import strategies as st
+from hypothesis.stateful import RuleBasedStateMachine, invariant, rule
 
 from repro.core.orchestrator import Orchestrator
 from repro.core.placement import (M_NODE_PROGRESS_RATE, M_TASK_PROGRESS,
@@ -332,101 +327,98 @@ def test_migration_controller_needs_peers_and_window():
 # ---------------------------------------------------------------------------
 # hypothesis state machine: scheduler + placement invariants
 # ---------------------------------------------------------------------------
-if HAS_HYPOTHESIS:
-    DOMAINS = {"node0": "dom0", "node1": "dom1", "node2": "dom0",
-               "node3": "dom1"}
+DOMAINS = {"node0": "dom0", "node1": "dom1", "node2": "dom0",
+           "node3": "dom1"}
 
-    class PlacementMachine(RuleBasedStateMachine):
-        """Random submit/schedule/finish interleavings under PRE_MG.
+class PlacementMachine(RuleBasedStateMachine):
+    """Random submit/schedule/finish interleavings under PRE_MG.
 
-        Invariants checked after every scheduling pass:
-        * no node is ever oversubscribed (replaying the pass's actions in
-          order never exceeds capacity);
-        * no task is lost or duplicated across deploy/evict/resume/migrate
-          (every submitted task sits in exactly one of wait/run/done);
-        * in eviction-free passes, a grouped deploy never lands in an
-          occupied failure domain while a conflict-free node with a free
-          slice existed (anti-affinity honored whenever feasible).
-        """
+    Invariants checked after every scheduling pass:
+    * no node is ever oversubscribed (replaying the pass's actions in
+      order never exceeds capacity);
+    * no task is lost or duplicated across deploy/evict/resume/migrate
+      (every submitted task sits in exactly one of wait/run/done);
+    * in eviction-free passes, a grouped deploy never lands in an
+      occupied failure domain while a conflict-free node with a free
+      slice existed (anti-affinity honored whenever feasible).
+    """
 
-        def __init__(self):
-            super().__init__()
-            self.view = RichView({n: 2 for n in DOMAINS}, domains=DOMAINS)
-            self.sched = FunkyScheduler(Policy.PRE_MG)
-            self.tasks = {}
-            self.done = set()
-            self.count = 0
+    def __init__(self):
+        super().__init__()
+        self.view = RichView({n: 2 for n in DOMAINS}, domains=DOMAINS)
+        self.sched = FunkyScheduler(Policy.PRE_MG)
+        self.tasks = {}
+        self.done = set()
+        self.count = 0
 
-        @rule(prio=st.integers(0, 3),
-              group=st.sampled_from([None, "svcA", "svcB"]))
-        def submit(self, prio, group):
-            tid = f"t{self.count}"
-            t = SchedTask(tid=tid, priority=prio, submit_time=self.count,
-                          group=group)
-            self.count += 1
-            self.tasks[tid] = t
-            self.sched.submit(t)
+    @rule(prio=st.integers(0, 3),
+          group=st.sampled_from([None, "svcA", "svcB"]))
+    def submit(self, prio, group):
+        tid = f"t{self.count}"
+        t = SchedTask(tid=tid, priority=prio, submit_time=self.count,
+                      group=group)
+        self.count += 1
+        self.tasks[tid] = t
+        self.sched.submit(t)
 
-        @rule(idx=st.integers(0, 7))
-        def finish(self, idx):
-            if not self.sched.run_queue:
-                return
-            t = self.sched.run_queue[idx % len(self.sched.run_queue)]
-            self.sched.task_done(t.tid)
-            self.view.used[t.node_id] -= 1
-            t.state = TaskState.DONE
-            self.done.add(t.tid)
+    @rule(idx=st.integers(0, 7))
+    def finish(self, idx):
+        if not self.sched.run_queue:
+            return
+        t = self.sched.run_queue[idx % len(self.sched.run_queue)]
+        self.sched.task_done(t.tid)
+        self.view.used[t.node_id] -= 1
+        t.state = TaskState.DONE
+        self.done.add(t.tid)
 
-        @rule()
-        def tick(self):
-            pre_groups = {}
-            for t in self.sched.run_queue:
-                if t.group and t.node_id:
-                    pre_groups.setdefault(t.group, []).append(
-                        DOMAINS[t.node_id])
-            free = {n: self.view.free_slices(n) for n in self.view.nodes()}
-            actions = self.sched.schedule_once(self.view)
-            evicted_in_pass = any(a.kind == "evict" for a in actions)
-            for a in actions:
-                if a.kind == "evict":
-                    free[a.node] += 1
-                    self.view.used[a.node] -= 1
-                    continue
-                if a.kind == "deploy" and not evicted_in_pass:
-                    grp = self.tasks[a.tid].group
-                    if grp:
-                        occupied = set(pre_groups.get(grp, []))
-                        feasible = any(
-                            free[n] > 0 and DOMAINS[n] not in occupied
-                            for n in self.view.nodes())
-                        if feasible:
-                            assert DOMAINS[a.node] not in occupied, (
-                                f"{a.tid} ({grp}) stacked into "
-                                f"{DOMAINS[a.node]} with a conflict-free "
-                                f"free node available")
-                free[a.node] -= 1
-                assert free[a.node] >= 0, f"{a.node} oversubscribed"
-                self.view.used[a.node] += 1
+    @rule()
+    def tick(self):
+        pre_groups = {}
+        for t in self.sched.run_queue:
+            if t.group and t.node_id:
+                pre_groups.setdefault(t.group, []).append(
+                    DOMAINS[t.node_id])
+        free = {n: self.view.free_slices(n) for n in self.view.nodes()}
+        actions = self.sched.schedule_once(self.view)
+        evicted_in_pass = any(a.kind == "evict" for a in actions)
+        for a in actions:
+            if a.kind == "evict":
+                free[a.node] += 1
+                self.view.used[a.node] -= 1
+                continue
+            if a.kind == "deploy" and not evicted_in_pass:
                 grp = self.tasks[a.tid].group
                 if grp:
-                    pre_groups.setdefault(grp, []).append(DOMAINS[a.node])
+                    occupied = set(pre_groups.get(grp, []))
+                    feasible = any(
+                        free[n] > 0 and DOMAINS[n] not in occupied
+                        for n in self.view.nodes())
+                    if feasible:
+                        assert DOMAINS[a.node] not in occupied, (
+                            f"{a.tid} ({grp}) stacked into "
+                            f"{DOMAINS[a.node]} with a conflict-free "
+                            f"free node available")
+            free[a.node] -= 1
+            assert free[a.node] >= 0, f"{a.node} oversubscribed"
+            self.view.used[a.node] += 1
+            grp = self.tasks[a.tid].group
+            if grp:
+                pre_groups.setdefault(grp, []).append(DOMAINS[a.node])
 
-        @invariant()
-        def capacity_and_conservation(self):
-            for n in self.view.nodes():
-                assert 0 <= self.view.used[n] <= self.view.capacity[n]
-            in_wait = {t.tid for t in self.sched.wait_queue}
-            in_run = {t.tid for t in self.sched.run_queue}
-            assert not (in_wait & in_run)
-            assert not (in_wait & self.done)
-            assert not (in_run & self.done)
-            assert in_wait | in_run | self.done == set(self.tasks)
-            # run-queue occupancy matches the view's accounting
-            assert len(in_run) == sum(self.view.used.values())
+    @invariant()
+    def capacity_and_conservation(self):
+        for n in self.view.nodes():
+            assert 0 <= self.view.used[n] <= self.view.capacity[n]
+        in_wait = {t.tid for t in self.sched.wait_queue}
+        in_run = {t.tid for t in self.sched.run_queue}
+        assert not (in_wait & in_run)
+        assert not (in_wait & self.done)
+        assert not (in_run & self.done)
+        assert in_wait | in_run | self.done == set(self.tasks)
+        # run-queue occupancy matches the view's accounting
+        assert len(in_run) == sum(self.view.used.values())
 
-    PlacementMachine.TestCase.settings = settings(
-        max_examples=40, stateful_step_count=30, deadline=None)
-    TestPlacementMachine = PlacementMachine.TestCase
-else:
-    def test_placement_state_machine():
-        pytest.importorskip("hypothesis")
+PlacementMachine.TestCase.settings = settings(
+    max_examples=40, stateful_step_count=30, deadline=None)
+TestPlacementMachine = PlacementMachine.TestCase
+

@@ -2,14 +2,8 @@
 
 import itertools
 
-import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-    HAS_HYPOTHESIS = True
-except ImportError:      # property tests skip; the rest of the module runs
-    HAS_HYPOTHESIS = False
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.scheduler import (Action, FunkyScheduler, Policy, SchedTask,
                                   TaskState)
@@ -55,56 +49,49 @@ def _drive(policy, n_nodes, slices, tasks):
     return sched, view, log
 
 
-if HAS_HYPOTHESIS:
-    @settings(max_examples=60, deadline=None)
-    @given(
-        policy=st.sampled_from(list(Policy)),
-        n_nodes=st.integers(1, 4),
-        slices=st.integers(1, 2),
-        prios=st.lists(st.integers(0, 3), min_size=1, max_size=10),
-    )
-    def test_capacity_and_queue_conservation(policy, n_nodes, slices, prios):
-        tasks = [SchedTask(tid=f"t{i}", priority=p, submit_time=i)
-                 for i, p in enumerate(prios)]
-        sched, view, log = _drive(policy, n_nodes, slices, tasks)
-        # each task is in exactly one queue
-        in_wait = {t.tid for t in sched.wait_queue}
-        in_run = {t.tid for t in sched.run_queue}
-        assert not (in_wait & in_run)
-        assert len(in_run) <= n_nodes * slices
-        # non-preemptive policies never evict
-        if policy in (Policy.FCFS, Policy.NO_PRE):
-            assert not [a for a in log if a.kind == "evict"]
-        # only PRE_MG migrates
-        if policy is not Policy.PRE_MG:
-            assert not [a for a in log if a.kind == "migrate"]
+@settings(max_examples=60, deadline=None)
+@given(
+    policy=st.sampled_from(list(Policy)),
+    n_nodes=st.integers(1, 4),
+    slices=st.integers(1, 2),
+    prios=st.lists(st.integers(0, 3), min_size=1, max_size=10),
+)
+def test_capacity_and_queue_conservation(policy, n_nodes, slices, prios):
+    tasks = [SchedTask(tid=f"t{i}", priority=p, submit_time=i)
+             for i, p in enumerate(prios)]
+    sched, view, log = _drive(policy, n_nodes, slices, tasks)
+    # each task is in exactly one queue
+    in_wait = {t.tid for t in sched.wait_queue}
+    in_run = {t.tid for t in sched.run_queue}
+    assert not (in_wait & in_run)
+    assert len(in_run) <= n_nodes * slices
+    # non-preemptive policies never evict
+    if policy in (Policy.FCFS, Policy.NO_PRE):
+        assert not [a for a in log if a.kind == "evict"]
+    # only PRE_MG migrates
+    if policy is not Policy.PRE_MG:
+        assert not [a for a in log if a.kind == "migrate"]
 
-    @settings(max_examples=40, deadline=None)
-    @given(prios=st.lists(st.integers(0, 3), min_size=2, max_size=8))
-    def test_preemption_always_favors_higher_priority(prios):
-        """PRE_EV: an evicted task's priority is strictly lower than a task
-        that was scheduled in the same pass."""
-        tasks = [SchedTask(tid=f"t{i}", priority=p, submit_time=i)
-                 for i, p in enumerate(prios)]
-        view = FakeView({"node0": 1})
-        sched = FunkyScheduler(Policy.PRE_EV)
-        for t in tasks:
-            sched.submit(t)
-            actions = sched.schedule_once(view)
-            view.apply(sched, actions)
-            evicted = [a for a in actions if a.kind == "evict"]
-            placed = [a for a in actions if a.kind in ("deploy", "resume")]
-            for e in evicted:
-                ep = next(x.priority for x in tasks if x.tid == e.tid)
-                assert any(
-                    next(x.priority for x in tasks if x.tid == p.tid) > ep
-                    for p in placed)
-else:
-    def test_capacity_and_queue_conservation():
-        pytest.importorskip("hypothesis")
-
-    def test_preemption_always_favors_higher_priority():
-        pytest.importorskip("hypothesis")
+@settings(max_examples=40, deadline=None)
+@given(prios=st.lists(st.integers(0, 3), min_size=2, max_size=8))
+def test_preemption_always_favors_higher_priority(prios):
+    """PRE_EV: an evicted task's priority is strictly lower than a task
+    that was scheduled in the same pass."""
+    tasks = [SchedTask(tid=f"t{i}", priority=p, submit_time=i)
+             for i, p in enumerate(prios)]
+    view = FakeView({"node0": 1})
+    sched = FunkyScheduler(Policy.PRE_EV)
+    for t in tasks:
+        sched.submit(t)
+        actions = sched.schedule_once(view)
+        view.apply(sched, actions)
+        evicted = [a for a in actions if a.kind == "evict"]
+        placed = [a for a in actions if a.kind in ("deploy", "resume")]
+        for e in evicted:
+            ep = next(x.priority for x in tasks if x.tid == e.tid)
+            assert any(
+                next(x.priority for x in tasks if x.tid == p.tid) > ep
+                for p in placed)
 
 
 def test_fcfs_is_head_of_line_blocking():
