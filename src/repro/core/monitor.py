@@ -29,6 +29,7 @@ from typing import Any, Optional
 
 import jax
 
+from repro import obs
 from repro.chaos import DEFAULT_EXECUTE_RETRY, RetryPolicy, TransientFault
 from repro.core.programs import Program, ProgramCache
 from repro.core.requests import (Completion, Direction, FunkyRequest,
@@ -203,26 +204,33 @@ class Monitor:
                 req.span.child("monitor.queue_wait",
                                t0=req.enqueue_t if req.enqueue_t is not None
                                else tc).end(tc)
-                req.mon_span = req.span.child(
-                    f"monitor.{req.kind.value.lower()}", t0=tc)
-            try:
-                value, error = self._handle_with_retry(req), None
-            except BaseException as e:  # noqa: BLE001 - forwarded to guest
-                value, error = None, e
-                if req.mon_span is not None:
-                    req.mon_span.annotate(error=repr(e))
-            dt = time.perf_counter() - t0
-            # phases must be complete before set() wakes the guest
-            req.completion.phases["total_s"] = dt
-            if req.mon_span is not None:
-                req.mon_span.end()
+            with obs.span(f"monitor.{req.kind.value.lower()}",
+                          parent=req.span,
+                          **self._span_labels(req)) as req.mon_span:
+                try:
+                    value, error = self._handle_with_retry(req), None
+                except BaseException as e:  # noqa: BLE001 - to the guest
+                    value, error = None, e
+                    if req.mon_span is not None:
+                        req.mon_span.annotate(error=repr(e))
+                dt = time.perf_counter() - t0
+                # phases must be complete before set() wakes the guest
+                req.completion.phases["total_s"] = dt
             req.completion.set(value, error=error)
             self._tel_queue_wait.observe(qw)
             self.metrics[f"n_{req.kind.value}"] += 1
-            self.metrics_hist[req.kind.value].append(dt)
             self._tel_count[req.kind.value].inc()
             self._tel_hist[req.kind.value].observe(dt)
             self._last_completion = req.completion
+
+    def _span_labels(self, req: FunkyRequest) -> dict:
+        """Labels of a request's ``monitor.<kind>`` span."""
+        if req.kind is RequestKind.EXECUTE:
+            return {"program": req.program_id}
+        if req.kind is RequestKind.TRANSFER and req.buff_id in self.buffers:
+            return {"direction": req.direction.value,
+                    "bytes": self.buffers.get(req.buff_id).nbytes}
+        return {}
 
     def _handle_with_retry(self, req: FunkyRequest) -> Any:
         """EXECUTEs get bounded retry-with-backoff on ``TransientFault``:
@@ -374,7 +382,9 @@ class Monitor:
                      hit=hit, program=req.program_id).end(tc)
             dev_sp = sp.child("execute.device", t0=tc,
                               program=req.program_id)
-        out = jax.block_until_ready(entry.compiled(*args))
+        with obs.span("monitor.launch", program=req.program_id):
+            out = entry.compiled(*args)
+        out = jax.block_until_ready(out)
         device_s = time.perf_counter() - t_run0
         if sp is not None:
             dev_sp.end()

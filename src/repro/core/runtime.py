@@ -23,6 +23,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
+from repro import obs
 from repro.core.guest import FunkyCL
 from repro.core.monitor import Monitor, MonitorState, NoSliceAvailable
 from repro.core.state import GuestState, TaskSnapshot
@@ -135,7 +136,8 @@ class FunkyRuntime:
                         # (evict/checkpoint) while waiting to acquire it
                         if not rec.run_gate.is_set():
                             continue
-                        done = rec.task.step(cl, rec.guest_state)
+                        with obs.span("runtime.step"):
+                            done = rec.task.step(cl, rec.guest_state)
                 rec.task.teardown(cl, rec.guest_state)
                 rec.status = TaskStatus.DONE
                 rec.log("done", step=rec.guest_state.step)

@@ -30,6 +30,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.scaling.autoscaler import (M_COMPLETIONS, M_KV_FREE_PAGES,
                                       M_KV_PAGES, M_LATENCY,
                                       M_PREFIX_HIT_RATE, M_QUEUE_DEPTH,
@@ -230,6 +231,10 @@ class RequestRouter:
     def pop(self, n: int, engine_id: Optional[str] = None) -> list:
         if n <= 0:
             return []
+        with obs.span("router.pop"):
+            return self._pop(n, engine_id)
+
+    def _pop(self, n: int, engine_id: Optional[str]) -> list:
         if self.chaos is not None:
             self.chaos.maybe_delay("router.pop", key=engine_id or "")
         with self._lock:

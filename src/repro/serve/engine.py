@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.guest import FunkyCL
 from repro.core.programs import Program
 from repro.models.attention import _INVALID_POS
@@ -1291,83 +1292,85 @@ class ContinuousBatchingEngine:
             if qsp is not None:
                 qsp.end()
                 req._eng_queue_span = None
-            adm = (req.trace.span("engine.admit", engine=self.engine_id,
-                                  slot=slot, bucket=bucket)
-                   if req.trace is not None else None)
-            admit_cs = []
-            read_c = None
-            first_tok = None
-            deferred_insert = None
-            if self.paged and self.prefix is not None:
-                first_tok, read_c, deferred_insert = self._admit_prefix(
-                    req, bucket, padded, match, page_ids, slot, adm)
-            elif (self.paged and self.spec is None
-                    and not self._legacy_admit):
-                # one-EXECUTE admission: prompt rides as a const arg, the
-                # program prefills, installs the lane and scatters the
-                # prompt pages in a single FIFO op
-                admit_cs.append(self._exec(
-                    f"prefill_admit_{bucket}",
-                    ("params", "toks", "pos", "kv_pool"),
-                    ("pf_tok", "toks", "pos", "kv_pool"),
-                    const_args=(self._pad_prompt(req.prompt, bucket),
-                                np.int32(slot),
-                                np.asarray(page_ids, np.int32)),
-                    donate=True,
-                    dirty_pages={"kv_pool": tuple(page_ids)}, span=adm))
-                self._bt_set_row(slot, page_ids)
-                if self._pipelined:
-                    # host-out-of-the-loop admission: the first token's
-                    # d2h read is deferred to the commit site — the host
-                    # never stalls behind the prefill EXECUTE, which now
-                    # overlaps this step's decode submit and commit work
-                    read_c = self._read_async("pf_tok", span=adm)
-                else:
-                    first_tok = int(np.asarray(self._read("pf_tok",
-                                                          span=adm))[0])
-            else:
-                admit_cs.append(self._write(
-                    f"pf_prompt_{bucket}",
-                    self._pad_prompt(req.prompt, bucket), span=adm))
-                admit_cs.append(self._exec(
-                    f"prefill_{bucket}",
-                    ("params", f"pf_prompt_{bucket}"),
-                    ("pf_tok", f"pf_cache_{bucket}"), span=adm))
-                if self.paged:
+            with obs.span("engine.admit",
+                          parent=(req.trace.root if req.trace is not None
+                                  else None),
+                          engine=self.engine_id, slot=slot,
+                          bucket=bucket) as adm:
+                admit_cs = []
+                read_c = None
+                first_tok = None
+                deferred_insert = None
+                if self.paged and self.prefix is not None:
+                    first_tok, read_c, deferred_insert = self._admit_prefix(
+                        req, bucket, padded, match, page_ids, slot, adm)
+                elif (self.paged and self.spec is None
+                        and not self._legacy_admit):
+                    # one-EXECUTE admission: prompt rides as a const arg, the
+                    # program prefills, installs the lane and scatters the
+                    # prompt pages in a single FIFO op
                     admit_cs.append(self._exec(
-                        f"admit_{bucket}",
-                        ("toks", "pos", "kv_pool", "pf_tok",
-                         f"pf_cache_{bucket}"),
-                        ("toks", "pos", "kv_pool"),
-                        const_args=(np.int32(slot),
+                        f"prefill_admit_{bucket}",
+                        ("params", "toks", "pos", "kv_pool"),
+                        ("pf_tok", "toks", "pos", "kv_pool"),
+                        const_args=(self._pad_prompt(req.prompt, bucket),
+                                    np.int32(slot),
                                     np.asarray(page_ids, np.int32)),
                         donate=True,
                         dirty_pages={"kv_pool": tuple(page_ids)}, span=adm))
                     self._bt_set_row(slot, page_ids)
-                    if self.spec is not None:
+                    if self._pipelined:
+                        # host-out-of-the-loop admission: the first token's
+                        # d2h read is deferred to the commit site — the host
+                        # never stalls behind the prefill EXECUTE, which now
+                        # overlaps this step's decode submit and commit work
+                        read_c = self._read_async("pf_tok", span=adm)
+                    else:
+                        first_tok = int(np.asarray(self._read("pf_tok",
+                                                              span=adm))[0])
+                else:
+                    admit_cs.append(self._write(
+                        f"pf_prompt_{bucket}",
+                        self._pad_prompt(req.prompt, bucket), span=adm))
+                    admit_cs.append(self._exec(
+                        f"prefill_{bucket}",
+                        ("params", f"pf_prompt_{bucket}"),
+                        ("pf_tok", f"pf_cache_{bucket}"), span=adm))
+                    if self.paged:
+                        admit_cs.append(self._exec(
+                            f"admit_{bucket}",
+                            ("toks", "pos", "kv_pool", "pf_tok",
+                             f"pf_cache_{bucket}"),
+                            ("toks", "pos", "kv_pool"),
+                            const_args=(np.int32(slot),
+                                        np.asarray(page_ids, np.int32)),
+                            donate=True,
+                            dirty_pages={"kv_pool": tuple(page_ids)},
+                            span=adm))
+                        self._bt_set_row(slot, page_ids)
+                        if self.spec is not None:
+                            self._exec(
+                                f"draft_prefill_{bucket}",
+                                ("draft_params", f"pf_prompt_{bucket}"),
+                                (f"pf_draft_cache_{bucket}",), span=adm)
+                            self._exec(
+                                f"admit_draft_{bucket}",
+                                ("draft_caches", f"pf_draft_cache_{bucket}"),
+                                ("draft_caches",),
+                                const_args=(np.int32(slot),), donate=True,
+                                span=adm)
+                    else:
                         self._exec(
-                            f"draft_prefill_{bucket}",
-                            ("draft_params", f"pf_prompt_{bucket}"),
-                            (f"pf_draft_cache_{bucket}",), span=adm)
-                        self._exec(
-                            f"admit_draft_{bucket}",
-                            ("draft_caches", f"pf_draft_cache_{bucket}"),
-                            ("draft_caches",),
+                            "admit_slot",
+                            ("toks", "pos", "caches", "pf_tok",
+                             f"pf_cache_{bucket}"),
+                            ("toks", "pos", "caches"),
                             const_args=(np.int32(slot),), donate=True,
                             span=adm)
-                else:
-                    self._exec(
-                        "admit_slot",
-                        ("toks", "pos", "caches", "pf_tok",
-                         f"pf_cache_{bucket}"),
-                        ("toks", "pos", "caches"),
-                        const_args=(np.int32(slot),), donate=True, span=adm)
-                # staged path (spec / reserved): the host mirror needs the
-                # first token synchronously
-                first_tok = int(np.asarray(self._read("pf_tok",
-                                                      span=adm))[0])
-            if adm is not None:
-                adm.end()
+                    # staged path (spec / reserved): the host mirror needs the
+                    # first token synchronously
+                    first_tok = int(np.asarray(self._read("pf_tok",
+                                                          span=adm))[0])
             if self.spec is not None:
                 self._toks_host[slot, 0] = first_tok
                 self._pos_host[slot] = bucket
@@ -1756,7 +1759,8 @@ class ContinuousBatchingEngine:
         if not self._should_auto_compact():
             return
         used, span = self.pool.used_count(), self.pool.used_span()
-        self.compact()
+        with obs.span("engine.pages"):
+            self.compact()
         self.auto_compactions += 1
         self.registry.record_event("engine_auto_compact",
                                    engine=self.engine_id, used=used,
@@ -1813,21 +1817,22 @@ class ContinuousBatchingEngine:
         delta outgrew its fixed-width buffer)."""
         if not self._bt_dirty:
             return
-        if self._bt_full or len(self._bt_delta) > self._bt_delta_width:
-            self._write("block_table", self._bt_host.copy(),
-                        span=self._it_root)
-            self.bt_full_writes += 1
-        else:
-            delta = np.full((self._bt_delta_width, 3), -1, np.int32)
-            if self._bt_delta:
-                delta[:len(self._bt_delta)] = self._bt_delta
-            self._exec("bt_update", ("block_table",), ("block_table",),
-                       const_args=(delta,), donate=True,
-                       span=self._it_root)
-            self.bt_delta_execs += 1
-        self._bt_full = False
-        self._bt_delta.clear()
-        self._bt_dirty = False
+        with obs.span("engine.pages"):
+            if self._bt_full or len(self._bt_delta) > self._bt_delta_width:
+                self._write("block_table", self._bt_host.copy(),
+                            span=self._it_root)
+                self.bt_full_writes += 1
+            else:
+                delta = np.full((self._bt_delta_width, 3), -1, np.int32)
+                if self._bt_delta:
+                    delta[:len(self._bt_delta)] = self._bt_delta
+                self._exec("bt_update", ("block_table",), ("block_table",),
+                           const_args=(delta,), donate=True,
+                           span=self._it_root)
+                self.bt_delta_execs += 1
+            self._bt_full = False
+            self._bt_delta.clear()
+            self._bt_dirty = False
 
     def _commit_tokens(self, st: _SlotState, tokens, now: float, *,
                        advance: bool = True) -> int:
@@ -1953,72 +1958,74 @@ class ContinuousBatchingEngine:
         failed span's device state is untouched and the next iteration
         resubmits it — bit-exact, since greedy decode recomputes the
         same tokens."""
-        rec = self._inflight.popleft()
-        kind, read_c = rec[0], rec[2]
-        err = None
-        try:
-            val = np.asarray(read_c.wait())
-        except BaseException as e:  # noqa: BLE001 - surfaced below
-            read_c.error_seen = True
-            err = e
-        if err is None:
-            # FIFO: the read completing proves every EXECUTE ahead of it
-            # was processed — surface their failures instead of committing
-            # stale bytes (a failed prefill leaves pf_tok untouched, and
-            # the read of those stale bytes itself succeeds)
-            for c in ((rec[1],) if kind == "batch" else rec[3]):
-                if c.error is not None:
-                    c.error_seen = True
-                    err = c.error
-                    break
-        if err is not None:
-            self._fail_pipeline([rec] + list(self._inflight))
-            raise err
-        now = self._clock()
-        if kind == "admit":
-            st = rec[1]
-            if self._active.get(st.slot) is not st:
-                return 0    # preempted since submit: recompute replays it
-            tok = int(val[0])
-            st.first_token_t = self._observe_first_token(st.req, now)
-            st.tokens.append(tok)
-            st.last_token_t = now
-            self._c_tokens.inc()
-            if st.deferred_insert is not None:
-                # prefix insert parked at admission: the tree needs the
-                # first token, which only just arrived
-                b, flat, ids = st.deferred_insert
-                self.prefix.insert(b, flat, ids, tok)
-                st.deferred_insert = None
-            if self.eos_id is not None and tok == self.eos_id:
-                self._mark_eos(st)
-            if len(st.tokens) >= st.limit and st.inflight == 0:
-                self._retire(st, now)   # degenerate 1-token request
-            return 1
-        decoded = 0
-        for st, n in rec[3]:
-            if self._active.get(st.slot) is not st:
-                continue    # preempted since submit: recompute replays it
-            st.inflight -= 1
-            if st.eos_done:
-                # the device lane was frozen for this whole span: nothing
-                # to commit, and pos/submitted were restored at EOS time
+        with obs.span("engine.commit"):
+            rec = self._inflight.popleft()
+            kind, read_c = rec[0], rec[2]
+            err = None
+            try:
+                val = np.asarray(read_c.wait())
+            except BaseException as e:  # noqa: BLE001 - surfaced below
+                read_c.error_seen = True
+                err = e
+            if err is None:
+                # FIFO: the read completing proves every EXECUTE ahead of
+                # it was processed — surface their failures instead of
+                # committing stale bytes (a failed prefill leaves pf_tok
+                # untouched, and the read of those stale bytes itself
+                # succeeds)
+                for c in ((rec[1],) if kind == "batch" else rec[3]):
+                    if c.error is not None:
+                        c.error_seen = True
+                        err = c.error
+                        break
+            if err is not None:
+                self._fail_pipeline([rec] + list(self._inflight))
+                raise err
+            now = self._clock()
+            if kind == "admit":
+                st = rec[1]
+                if self._active.get(st.slot) is not st:
+                    return 0    # preempted since submit: recompute replays it
+                tok = int(val[0])
+                st.first_token_t = self._observe_first_token(st.req, now)
+                st.tokens.append(tok)
+                st.last_token_t = now
+                self._c_tokens.inc()
+                if st.deferred_insert is not None:
+                    # prefix insert parked at admission: the tree needs the
+                    # first token, which only just arrived
+                    b, flat, ids = st.deferred_insert
+                    self.prefix.insert(b, flat, ids, tok)
+                    st.deferred_insert = None
+                if self.eos_id is not None and tok == self.eos_id:
+                    self._mark_eos(st)
+                if len(st.tokens) >= st.limit and st.inflight == 0:
+                    self._retire(st, now)   # degenerate 1-token request
+                return 1
+            decoded = 0
+            for st, n in rec[3]:
+                if self._active.get(st.slot) is not st:
+                    continue    # preempted since submit: recompute replays it
+                st.inflight -= 1
+                if st.eos_done:
+                    # the device lane was frozen for this whole span: nothing
+                    # to commit, and pos/submitted were restored at EOS time
+                    if len(st.tokens) >= st.limit and st.inflight == 0:
+                        self._retire(st, now)
+                    continue
+                toks = np.asarray(val[st.slot, :n])
+                if self.eos_id is not None:
+                    hit = np.nonzero(toks == self.eos_id)[0]
+                    if hit.size:
+                        toks = toks[:int(hit[0]) + 1]
+                decoded += self._commit_tokens(st, toks, now, advance=False)
+                if (self.eos_id is not None and st.tokens
+                        and st.tokens[-1] == self.eos_id):
+                    self._mark_eos(st)
                 if len(st.tokens) >= st.limit and st.inflight == 0:
                     self._retire(st, now)
-                continue
-            toks = np.asarray(val[st.slot, :n])
-            if self.eos_id is not None:
-                hit = np.nonzero(toks == self.eos_id)[0]
-                if hit.size:
-                    toks = toks[:int(hit[0]) + 1]
-            decoded += self._commit_tokens(st, toks, now, advance=False)
-            if (self.eos_id is not None and st.tokens
-                    and st.tokens[-1] == self.eos_id):
-                self._mark_eos(st)
-            if len(st.tokens) >= st.limit and st.inflight == 0:
-                self._retire(st, now)
-        self._c_tokens.inc(decoded)
-        return decoded
+            self._c_tokens.inc(decoded)
+            return decoded
 
     def _mark_eos(self, st: _SlotState) -> None:
         """The lane's newest committed token is the stop token.  Clamp the
@@ -2251,7 +2258,8 @@ class ContinuousBatchingEngine:
         if not self._setup_done:
             raise RuntimeError("engine.setup() has not run")
         try:
-            return self._step_inner()
+            with obs.span("engine.step"):
+                return self._step_inner()
         except BaseException as e:  # noqa: BLE001 - dump, then re-raise
             try:
                 path = os.path.join(
@@ -2287,7 +2295,8 @@ class ContinuousBatchingEngine:
             admitted = self._admit()
             self.peak_active = max(self.peak_active, len(self._active))
             if self._active and self.paged:
-                self._append_pages()
+                with obs.span("engine.pages"):
+                    self._append_pages()
             if self._active and self.spec is not None:
                 decoded += self._spec_iteration()
             elif self.paged and (self.fuse_steps > 1
@@ -2314,17 +2323,18 @@ class ContinuousBatchingEngine:
                 # token delivery doubles as the iteration's sync point —
                 # the d2h TRANSFER drains the queue, landing on a token
                 # boundary
-                toks = np.asarray(self._read("toks", span=self._it_root))
-                now = self._clock()
-                for st in list(self._active.values()):
-                    decoded += self._commit_tokens(
-                        st, toks[st.slot], now)
-                    if (self.eos_id is not None and st.tokens
-                            and st.tokens[-1] == self.eos_id):
-                        self._mark_eos(st)
-                    if len(st.tokens) >= st.limit:
-                        self._retire(st, now)
-                self._c_tokens.inc(decoded)
+                with obs.span("engine.commit"):
+                    toks = np.asarray(self._read("toks", span=self._it_root))
+                    now = self._clock()
+                    for st in list(self._active.values()):
+                        decoded += self._commit_tokens(
+                            st, toks[st.slot], now)
+                        if (self.eos_id is not None and st.tokens
+                                and st.tokens[-1] == self.eos_id):
+                            self._mark_eos(st)
+                        if len(st.tokens) >= st.limit:
+                            self._retire(st, now)
+                    self._c_tokens.inc(decoded)
         finally:
             self._mid_step = False
         self.iterations += 1

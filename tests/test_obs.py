@@ -2,7 +2,8 @@
 keep-slowest + probabilistic sampling), Chrome-trace export round-trip,
 and end-to-end instrumentation — a router->engine->monitor request forms
 one connected span tree, monitor phase attribution sums to no more than
-the handler wall time, and the engine's host/device split is publishable."""
+the handler wall time, and the engine's host/device split is publishable.
+Scoped spans land in the profiler's trace, nested as the code nests them."""
 
 import json
 import os
@@ -13,6 +14,7 @@ import pytest
 
 from repro.core import FunkyCL, Monitor, SliceAllocator
 from repro.core.simulator import ServingSimulator
+from repro import obs
 from repro.obs import (Tracer, chrome_trace_events, export_chrome_trace,
                        validate_chrome_trace)
 from repro.scaling import burst_rate, open_loop
@@ -84,6 +86,24 @@ def test_parent_defaults_to_root_and_context_manager():
     assert sp.end_t == 2.0
     assert sp.end(t=99.0).end_t == 2.0          # end() is idempotent
     assert sp.parent_id == tr.root.span_id
+
+
+def test_scoped_span_records_obs_child_only_with_parent():
+    clk = FakeClock()
+    tr = Tracer(clock=clk).start_trace("t")
+    with obs.span("engine.admit", parent=tr.root, slot=3) as sp:
+        clk.now = 1.5
+    assert sp.name == "engine.admit" and sp.labels == {"slot": 3}
+    assert sp.parent_id == tr.root.span_id
+    assert (sp.start_t, sp.end_t) == (0.0, 1.5)
+    with obs.span("engine.step", slot=3) as none:
+        pass
+    assert none is None
+    assert [s.name for s in tr.spans()] == ["t", "engine.admit"]
+    with pytest.raises(KeyError):
+        with obs.span("engine.commit", parent=tr.root) as failed:
+            raise KeyError("x")
+    assert failed.end_t is not None             # closed on the way out
 
 
 def test_trace_span_ring_never_evicts_root():
@@ -389,3 +409,71 @@ def test_untraced_engine_still_attributes_phases():
     split = eng.host_device_split()
     assert split["tokens"] == 4
     assert split["device_us_per_token"] > 0.0
+
+
+# ---------------------------------------------------------------------------
+# Scoped spans on the profiler's clock
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def profiled_run(tmp_path_factory):
+    """A short engine run under the JAX profiler: the profile's host
+    lines, each ``[(start_ns, end_ns, name, stats)]``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    reg = MetricsRegistry()
+    mon = Monitor("obs-prof", SliceAllocator("n3", 1), telemetry=reg)
+    eng = ContinuousBatchingEngine(ARCH, FunkyCL(mon), slots=2,
+                                   prompt_len=PROMPT_LEN, max_new_tokens=4,
+                                   registry=reg, page_size=PAGE)
+    eng.setup()
+    rng = np.random.Generator(np.random.Philox(2))
+    eng.submit(ServeRequest(rid="q0", prompt=rng.integers(0, 100, PROMPT_LEN),
+                            max_new_tokens=3))
+    eng.step()                                  # compiled before the trace
+    log_dir = tmp_path_factory.mktemp("prof")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        eng.run_until_drained()
+    finally:
+        jax.profiler.stop_trace()
+        mon.vfpga_exit()
+    (path,) = log_dir.glob("plugins/profile/*/*.xplane.pb")
+    lines = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                lines.append([(e.start_ns, e.start_ns + e.duration_ns,
+                               e.name, dict(e.stats)) for e in line.events
+                              if e.name.startswith("funky.")])
+    return [ln for ln in lines if ln]
+
+
+def _inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+def test_scoped_spans_nest_in_the_profile(profiled_run):
+    names = {e[2] for ln in profiled_run for e in ln}
+    assert {"funky.engine.step", "funky.engine.commit",
+            "funky.monitor.execute", "funky.monitor.launch"} <= names
+    for ln in profiled_run:
+        steps = [e for e in ln if e[2] == "funky.engine.step"]
+        execs = [e for e in ln if e[2] == "funky.monitor.execute"]
+        for e in ln:
+            if e[2] == "funky.engine.commit":
+                # the engine's spans run on the driver's thread, inside
+                # its step
+                assert any(_inside(e, s) for s in steps)
+            if e[2] == "funky.monitor.launch":
+                # the monitor's worker thread: each launch inside the
+                # EXECUTE of the same program, which it labels
+                outer = [x for x in execs if _inside(e, x)]
+                assert len(outer) == 1
+                assert e[3]["program"] == outer[0][3]["program"]
+                assert not steps        # another thread than the engine's
+    launches = [e for ln in profiled_run for e in ln
+                if e[2] == "funky.monitor.launch"]
+    assert any(e[3]["program"] == "decode_step" for e in launches)
