@@ -96,6 +96,7 @@ from repro.serve.kvcache import (BlockPool, _is_pos_leaf,
                                  compact_pool, extract_pool_pages,
                                  extract_written_page, gather_lane_cache,
                                  init_caches_from_specs, install_pool_pages,
+                                 merged_pool_leaves,
                                  pool_specs_from_lane_cache, scatter_pages,
                                  scatter_prefill, scrub_pages,
                                  token_axes_from_lengths)
@@ -116,6 +117,9 @@ M_SPEC_K = "spec_k"                 # live speculative lookahead per engine
 M_HOST_US = "host_us_per_token"
 M_DEVICE_US = "device_us_per_token"
 M_QUEUE_WAIT_US = "queue_wait_us"
+# k/v pool leaves stored with (heads, head_dim) merged, and their bytes
+M_KV_MERGED_LEAVES = "kv_pool_merged_leaves"
+M_KV_MERGED_BYTES = "kv_pool_merged_bytes"
 
 
 @dataclass(frozen=True)
@@ -585,11 +589,18 @@ class ContinuousBatchingEngine:
         token_axes = token_axes_from_lengths(
             alt_cache, pf_abs[self.prompt_len][2], alt, self.prompt_len)
         self._token_axes = token_axes
-        pool_abs = pool_specs_from_lane_cache(
-            pf_abs[self.prompt_len][2], token_axes, NP, ps)
+        lane_abs = pf_abs[self.prompt_len][2]
+        pool_abs = pool_specs_from_lane_cache(lane_abs, token_axes, NP, ps)
         self._pool_abs = pool_abs
         self.pool_bytes = cache_bytes(pool_abs)
         self.page_bytes = self.pool_bytes // NP
+        merged = merged_pool_leaves(pool_abs, lane_abs)
+        self.kv_merged_leaves = len(merged)
+        self.kv_merged_bytes = cache_bytes(merged)
+        self.registry.gauge(M_KV_MERGED_LEAVES, service=self.service,
+                            engine=self.engine_id).set(len(merged))
+        self.registry.gauge(M_KV_MERGED_BYTES, service=self.service,
+                            engine=self.engine_id).set(self.kv_merged_bytes)
         toks_abs = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         pos_abs = jax.ShapeDtypeStruct((B,), jnp.int32)
         bt_abs = jax.ShapeDtypeStruct((B, max_blocks), jnp.int32)
@@ -600,8 +611,8 @@ class ContinuousBatchingEngine:
 
         def decode_step(params, toks, pos, bt, pool):
             def lane(tok, p, bt_row):
-                caches = gather_lane_cache(pool, bt_row, token_axes,
-                                           page_size=ps)
+                caches = gather_lane_cache(pool, bt_row, lane_abs,
+                                           token_axes, page_size=ps)
                 logits, new_cache = bundle.decode_fn(params, tok, p, caches)
                 new_tok = jnp.argmax(logits, -1).astype(jnp.int32)
                 active = bt_row[0] >= 0
@@ -639,8 +650,8 @@ class ContinuousBatchingEngine:
             n_span = (kf - 1) // ps + 2
 
             def lane(tok, p, bt_row, lim):
-                cache = gather_lane_cache(pool, bt_row, token_axes,
-                                          page_size=ps)
+                cache = gather_lane_cache(pool, bt_row, lane_abs,
+                                          token_axes, page_size=ps)
                 on = bt_row[0] >= 0
                 lim = jnp.clip(lim, 0, kf)
                 # on-device stop-token detection: EOS folds into the same
@@ -787,8 +798,8 @@ class ContinuousBatchingEngine:
 
             def prefill_chunk(params, pool, chunk_toks, lp, bt_row):
                 lp = jnp.asarray(lp, jnp.int32)
-                cache = gather_lane_cache(pool, bt_row, token_axes,
-                                          page_size=ps)
+                cache = gather_lane_cache(pool, bt_row, lane_abs,
+                                          token_axes, page_size=ps)
                 pos0 = lp * jnp.int32(ps)
                 logits = None
                 for i in range(ps):
@@ -874,7 +885,7 @@ class ContinuousBatchingEngine:
                            donate_argnums=(0, 1))
         if self.spec is not None:
             self._setup_spec(params_abs, toks_abs, pos_abs, bt_abs, pool_abs,
-                             token_axes)
+                             lane_abs, token_axes)
         if not restore:
             cl.clCreateBuffer("params", params_abs)
             cl.clCreateBuffer("toks", toks_abs)
@@ -928,7 +939,7 @@ class ContinuousBatchingEngine:
 
     # -- speculative decode: draft + verify programs ---------------------
     def _setup_spec(self, params_abs, toks_abs, pos_abs, bt_abs, pool_abs,
-                    token_axes) -> None:
+                    lane_abs, token_axes) -> None:
         spec, bundle, dbundle = self.spec, self.bundle, self.draft_bundle
         B, ps, k = self.slots, self.page_size, self.spec_k
         NP, max_blocks = self.pool_pages, self.max_blocks
@@ -1007,8 +1018,8 @@ class ContinuousBatchingEngine:
 
             def verify_step(params, toks, d_toks, pos, bt, pool):
                 def lane(tok, drafts, p, bt_row):
-                    cache = gather_lane_cache(pool, bt_row, token_axes,
-                                              page_size=ps)
+                    cache = gather_lane_cache(pool, bt_row, lane_abs,
+                                              token_axes, page_size=ps)
                     cur, outs = tok, []
                     for i in range(v + 1):
                         logits, cache = bundle.decode_fn(
@@ -1225,6 +1236,8 @@ class ContinuousBatchingEngine:
         used = self.pool.used_count()
         return {"paged": True, "pool_bytes": self.pool_bytes,
                 "page_bytes": self.page_bytes,
+                "merged_leaves": self.kv_merged_leaves,
+                "merged_bytes": self.kv_merged_bytes,
                 "pages_used": used, "pages_free": self.pool.free_count(),
                 "bytes_in_use": used * self.page_bytes,
                 "occupancy": self.pool.occupancy(),

@@ -27,7 +27,9 @@ virtualizes that memory behind an indirection layer, PagedAttention-style:
 
 Every leaf of the device pool has the page axis as axis 0, matching the
 ``BufferTable``'s page-granular dirtiness: evict/checkpoint serialize only
-the pages written since the last sync plus the (tiny) block table.
+the pages written since the last sync plus the (tiny) block table.  A k/v
+leaf stores its trailing ``(heads, head_dim)`` as one axis: a TPU then lays
+the pool out page-major, one page one contiguous block, for any head_dim.
 """
 
 from __future__ import annotations
@@ -228,7 +230,13 @@ class BlockPool:
 # prefill cache at two prompt lengths; every traced helper then normalizes
 # a leaf by moving that axis to the front, so the pool layout is always
 # ``(num_pages, page_size, *rest)`` with ``rest`` the per-token residue in
-# original order (layer/batch/head axes included).
+# original order (layer/batch/head axes included) — except that a k/v leaf
+# merges the last two axes of ``rest`` (``(heads, head_dim)``) into one.
+# With both in the tiled minor dims, a TPU's default layout pads head_dim
+# to 128 lanes unless it puts the page axis minor-most instead; then one
+# page is scattered across the whole leaf, a gather of pages crawls and
+# every page scatter relayouts the pool.  Merged, the page axis stays
+# major.  The merge pads nothing; position leaves keep their shape.
 
 def token_axes_from_lengths(cache_a, cache_b, len_a: int, len_b: int, *,
                             exact: bool = True):
@@ -265,25 +273,49 @@ def _token_first(leaf, axis):
     return jnp.moveaxis(leaf, axis, 0)
 
 
+def _lane_rest(leaf, axis):
+    """Per-token shape of a lane cache leaf: its shape less the token axis."""
+    return leaf.shape[:axis] + leaf.shape[axis + 1:]
+
+
+def _stored_rest(path, rest):
+    """Per-token shape a pool leaf stores: ``rest`` with its last two axes
+    merged, except for position leaves and leaves with fewer axes."""
+    if _is_pos_leaf(path) or len(rest) < 2:
+        return rest
+    return rest[:-2] + (rest[-2] * rest[-1],)
+
+
 def pool_specs_from_lane_cache(lane_cache_abs, token_axes, num_pages: int,
                                page_size: int):
     """Per-lane cache pytree -> page-pool pytree: each leaf becomes
-    ``(num_pages, page_size, *rest)``.  Structure (and the ``kv_pos`` leaf
-    names the init helper keys on) is preserved."""
-    def mk(leaf, axis):
-        rest = leaf.shape[:axis] + leaf.shape[axis + 1:]
+    ``(num_pages, page_size, *rest)``, a k/v leaf with the last two axes of
+    ``rest`` merged.  Structure (and the ``kv_pos`` leaf names the init
+    helper keys on) is preserved."""
+    def mk(path, leaf, axis):
+        rest = _stored_rest(path, _lane_rest(leaf, axis))
         return jax.ShapeDtypeStruct((num_pages, page_size) + rest,
                                     leaf.dtype)
 
-    return jax.tree.map(mk, lane_cache_abs, token_axes)
+    return jax.tree_util.tree_map_with_path(mk, lane_cache_abs, token_axes)
+
+
+def merged_pool_leaves(pool, lane_cache_abs):
+    """The pool leaves stored with two per-token axes merged into one."""
+    return [p for p, l in zip(jax.tree.leaves(pool),
+                              jax.tree.leaves(lane_cache_abs))
+            if p.ndim == l.ndim]
 
 
 # ---------------------------------------------------------------------------
 # Traced kernel-side helpers
 # ---------------------------------------------------------------------------
-def gather_lane_cache(pool, block_row, token_axes, *, page_size: int):
+def gather_lane_cache(pool, block_row, lane_cache_abs, token_axes, *,
+                      page_size: int):
     """Reassemble one lane's logical cache from the pool through its block
-    table row (traced, vmapped over lanes by the engine).
+    table row (traced, vmapped over lanes by the engine).  The lane cache
+    the pool was built from gives each leaf's per-token shape, merged
+    axes split back.
 
     Unmapped pages (id < 0) are clamped for the gather but their positions
     are forced to the INVALID sentinel, so attention masks them out no
@@ -292,10 +324,15 @@ def gather_lane_cache(pool, block_row, token_axes, *, page_size: int):
     max_blocks = block_row.shape[0]
     cap = max_blocks * page_size
 
-    def gk(path, leaf, axis):
+    def gk(path, leaf, lane, axis):
         safe = jnp.clip(block_row, 0, leaf.shape[0] - 1)
-        pages = leaf[safe]                       # (max_blocks, ps, *rest)
-        flat = pages.reshape((cap,) + leaf.shape[2:])
+        # gather token rows, not whole pages: a page of a wide model (1.3 MB
+        # for stablelm-3b) makes the TPU gather in slices of the merged axis
+        # and stitch them back (a 40.6 ms decode step against 38.0 ms, v5e)
+        rows = (safe[:, None] * page_size
+                + jnp.arange(page_size, dtype=safe.dtype)).reshape(cap)
+        flat = leaf.reshape((-1,) + leaf.shape[2:])[rows]
+        flat = flat.reshape((cap,) + _lane_rest(lane, axis))
         if _is_pos_leaf(path):
             valid = jnp.repeat(block_row >= 0, page_size)
             flat = jnp.where(
@@ -303,7 +340,8 @@ def gather_lane_cache(pool, block_row, token_axes, *, page_size: int):
                 flat, _INVALID_POS)
         return jnp.moveaxis(flat, 0, axis)       # original lane layout
 
-    return jax.tree_util.tree_map_with_path(gk, pool, token_axes)
+    return jax.tree_util.tree_map_with_path(gk, pool, lane_cache_abs,
+                                            token_axes)
 
 
 def extract_written_page(new_lane_cache, logical_page, token_axes, *,
@@ -320,11 +358,14 @@ def extract_written_page(new_lane_cache, logical_page, token_axes, *,
 
 
 def scatter_pages(pool, phys_ids, pages):
-    """Write per-lane updated pages back into the pool.  ``phys_ids`` is
-    (lanes,); out-of-range ids (inactive lanes) are dropped.  Active lanes
-    own disjoint pages, so the scatter is conflict-free."""
+    """Write per-lane updated pages (``extract_written_page`` layout, or
+    the pool's own) back into the pool.  ``phys_ids`` is (lanes,);
+    out-of-range ids (inactive lanes) are dropped.  Active lanes own
+    disjoint pages, so the scatter is conflict-free."""
     return jax.tree.map(
-        lambda pl, pg: pl.at[phys_ids].set(pg, mode="drop"), pool, pages)
+        lambda pl, pg: pl.at[phys_ids].set(
+            pg.reshape(pg.shape[:1] + pl.shape[1:]), mode="drop"),
+        pool, pages)
 
 
 def scatter_prefill(pool, page_ids, pf_cache, token_axes, *,
@@ -344,7 +385,7 @@ def scatter_prefill(pool, page_ids, pf_cache, token_axes, *,
                              jnp.int32) if _is_pos_leaf(path)
                     else jnp.zeros((pad,) + vals.shape[1:], vals.dtype))
             vals = jnp.concatenate([vals, fill])
-        vals = vals.reshape((n_pp, page_size) + vals.shape[1:])
+        vals = vals.reshape((n_pp,) + pool_leaf.shape[1:])
         return pool_leaf.at[page_ids].set(vals)
 
     return jax.tree_util.tree_map_with_path(sc, pool, pf_cache, token_axes)

@@ -10,8 +10,11 @@ import pytest
 from repro.core.state import BufferState, BufferTable, tree_bytes
 from repro.models.attention import _INVALID_POS
 from repro.serve.kvcache import (BlockPool, BlockPoolError, cache_bytes,
-                                 gather_lane_cache, pool_specs_from_lane_cache,
-                                 scatter_pages, scatter_prefill, scrub_pages,
+                                 compact_pool, extract_pool_pages,
+                                 extract_written_page, gather_lane_cache,
+                                 install_pool_pages, merged_pool_leaves,
+                                 pool_specs_from_lane_cache, scatter_pages,
+                                 scatter_prefill, scrub_pages,
                                  token_axes_from_lengths)
 
 from hypothesis import given, settings
@@ -299,7 +302,7 @@ def test_token_axes_delta_mode_for_margined_caches():
 
 def test_pool_specs_shapes(axes):
     pool = pool_specs_from_lane_cache(_abs(_lane_cache(8)), axes, NP_, PS)
-    assert pool["k"].shape == (NP_, PS, 2, 1, 2, 3)
+    assert pool["k"].shape == (NP_, PS, 2, 1, 6)     # (heads, hd) merged
     assert pool["kv_pos"].shape == (NP_, PS, 2)
     # byte accounting goes through the one shared helper
     assert cache_bytes(pool) == tree_bytes(pool)
@@ -320,7 +323,7 @@ def test_prefill_scatter_gather_roundtrip(axes):
     pool = scatter_prefill(pool, page_ids, lane, axes, page_size=PS,
                            prompt_len=cap)
     block_row = jnp.asarray([4, 1, -1], jnp.int32)
-    got = gather_lane_cache(pool, block_row, axes, page_size=PS)
+    got = gather_lane_cache(pool, block_row, _abs(lane), axes, page_size=PS)
     L = MB * PS
     assert got["k"].shape == (2, 1, L, 2, 3)
     np.testing.assert_array_equal(np.asarray(got["k"][:, :, :cap]),
@@ -353,6 +356,115 @@ def test_scatter_pages_drops_inactive_lanes(axes):
     assert (np.asarray(out["k"][3]) == 1).all()
     assert (np.asarray(out["k"][:3]) == 0).all()
     assert (np.asarray(out["k"][4:]) == 0).all()
+
+
+def _merged_page(lane_leaf, axis, page):
+    """Page ``page`` of a lane leaf as the pool stores it: token-first,
+    (heads, head_dim) merged."""
+    tf = np.moveaxis(np.asarray(lane_leaf), axis, 0)
+    return tf[page * PS:(page + 1) * PS].reshape(
+        (PS,) + tf.shape[1:-2] + (tf.shape[-2] * tf.shape[-1],))
+
+
+def _filled_pool(axes, lane, page_ids):
+    pool_abs = pool_specs_from_lane_cache(_abs(_lane_cache(MB * PS)), axes,
+                                          NP_, PS)
+    pool = jax.tree_util.tree_map_with_path(
+        lambda p, l: (jnp.full(l.shape, _INVALID_POS, jnp.int32)
+                      if p[-1].key == "kv_pos"
+                      else jnp.full(l.shape, 99.0, l.dtype)), pool_abs)
+    return scatter_prefill(pool, jnp.asarray(page_ids, jnp.int32), lane,
+                           axes, page_size=PS, prompt_len=2 * PS)
+
+
+def _same_page(a, i, b, j):
+    """Page ``i`` of pool ``a`` equals page ``j`` of pool ``b``, every leaf."""
+    return all((np.asarray(x[i]) == np.asarray(y[j])).all()
+               for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)))
+
+
+@pytest.mark.parametrize("check", ["prefill_gather", "written_page",
+                                   "extract_install", "compact",
+                                   "pool_bytes", "engine_counter"])
+def test_merged_kv_leaves_move_whole_pages(axes, check):
+    """k/v pool leaves store (heads, head_dim) as one axis; every helper
+    moves whole pages of that layout and the lane cache comes back exact."""
+    lane = _lane_cache(2 * PS)
+    lane["v"] = -1.5 * lane["k"]                 # distinct from k
+    pool = _filled_pool(axes, lane, [4, 1])
+    if check == "prefill_gather":
+        for j, phys in enumerate([4, 1]):
+            for name in ("k", "v"):
+                np.testing.assert_array_equal(
+                    np.asarray(pool[name][phys]),
+                    _merged_page(lane[name], axes[name], j))
+        got = gather_lane_cache(pool, jnp.asarray([4, 1, -1], jnp.int32),
+                                _abs(lane), axes, page_size=PS)
+        for name in ("k", "v", "kv_pos"):
+            np.testing.assert_array_equal(
+                np.asarray(jnp.moveaxis(got[name], axes[name], 0)[:2 * PS]),
+                np.asarray(jnp.moveaxis(lane[name], axes[name], 0)))
+    elif check == "written_page":
+        row = jnp.asarray([4, 1, -1], jnp.int32)
+        cache = gather_lane_cache(pool, row, _abs(lane), axes, page_size=PS)
+        t = PS + 2                               # logical page 1, slot 2
+        cache["k"] = cache["k"].at[:, :, t].set(7.0)
+        cache["v"] = cache["v"].at[:, :, t].set(-7.0)
+        page = extract_written_page(cache, jnp.int32(1), axes, page_size=PS)
+        out = scatter_pages(pool, jnp.asarray([1], jnp.int32),
+                            jax.tree.map(lambda x: x[None], page))
+        assert all(_same_page(out, i, pool, i) for i in range(NP_) if i != 1)
+        np.testing.assert_array_equal(np.asarray(out["kv_pos"][1]),
+                                      np.asarray(pool["kv_pos"][1]))
+        for name, val in (("k", 7.0), ("v", -7.0)):
+            got, was = np.asarray(out[name][1]), np.asarray(pool[name][1])
+            assert (got[2] == val).all()
+            np.testing.assert_array_equal(np.delete(got, 2, 0),
+                                          np.delete(was, 2, 0))
+    elif check == "extract_install":
+        staged = extract_pool_pages(pool, jnp.asarray([4, 1, NP_], jnp.int32))
+        empty = jax.tree.map(jnp.zeros_like, pool)
+        out = install_pool_pages(empty, staged,
+                                 jnp.asarray([0, 2, NP_], jnp.int32))
+        assert _same_page(out, 0, pool, 4) and _same_page(out, 2, pool, 1)
+        assert all(_same_page(out, i, empty, i) for i in (1, 3, 4, 5))
+    elif check == "compact":
+        out = compact_pool(pool, jnp.asarray([4, NP_], jnp.int32),
+                           jnp.asarray([0, NP_], jnp.int32))
+        assert _same_page(out, 0, pool, 4)
+        assert all(_same_page(out, i, pool, i) for i in range(1, NP_))
+    elif check == "pool_bytes":
+        pool_abs = _abs(pool)
+        # what the (heads, head_dim) pool held: k and v of 2x1x2x3 float32
+        # per token, kv_pos of 2 int32
+        assert cache_bytes(pool_abs) == NP_ * PS * (2 * 12 * 4 + 2 * 4)
+        merged = merged_pool_leaves(pool_abs, _abs(lane))
+        assert len(merged) == 2
+        assert cache_bytes(merged) == NP_ * PS * 2 * 12 * 4
+    else:
+        from repro.core import FunkyCL, Monitor, SliceAllocator
+        from repro.scaling.metrics import MetricsRegistry
+        from repro.serve.engine import ContinuousBatchingEngine
+
+        reg = MetricsRegistry()
+        mon = Monitor("kv-test", SliceAllocator("n0", 1), telemetry=reg)
+        eng = ContinuousBatchingEngine("yi-9b-smoke", FunkyCL(mon), slots=2,
+                                       prompt_len=8, max_new_tokens=8,
+                                       registry=reg, page_size=PS)
+        try:
+            eng.setup()
+            cfg = eng.cfg
+            assert eng.kv_stats()["merged_leaves"] == 2
+            # bf16 k and v, int32 kv_pos: per token, as before the merge
+            kv = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * 2
+            assert eng.kv_stats()["merged_bytes"] == eng.pool_pages * PS * kv
+            assert eng.pool_bytes == eng.pool_pages * PS * (
+                kv + cfg.num_layers * 4)
+            gauges = reg.snapshot()["gauges"]
+            assert [v for k, v in gauges.items()
+                    if k.startswith("kv_pool_merged_leaves")] == [2]
+        finally:
+            mon.vfpga_exit()
 
 
 # ---------------------------------------------------------------------------
