@@ -32,6 +32,30 @@ class MoEConfig:
     dense_d_ff: int = 0             # hidden dim of those dense layers
     capacity_factor: float = 1.25   # capacity-based dispatch (GShard-style)
     router_aux_weight: float = 0.001
+    # router of the held path below, DeepSeek-V3's noaux_tc: sigmoid
+    # scores ranked with a selection bias (e_score_correction_bias);
+    # experts are chosen among the best ``topk_group`` of ``n_group``
+    # groups, each group scored by the sum of its two best; the chosen
+    # scores are normalised and scaled.  The capacity path routes by
+    # softmax top-k.
+    n_group: int = 0                # 0 -> no group limit
+    topk_group: int = 0
+    routed_scaling_factor: float = 1.0
+    # one chip's share of an expert-parallel deployment: experts
+    # ``expert_offset .. expert_offset + experts_held - 1`` live here and
+    # every routed pair that lands on them is computed (no capacity, no
+    # drop); 0 holds every expert on the capacity path above
+    experts_held: int = 0
+    expert_offset: int = 0
+
+    def __post_init__(self):
+        # the capacity path would ignore noaux_tc's settings
+        if not self.experts_held and (
+                self.n_group, self.topk_group,
+                self.routed_scaling_factor) != (0, 0, 1.0):
+            raise ValueError("expert groups and a routed scaling factor "
+                             "need experts_held: the capacity path routes "
+                             "by softmax top-k")
 
     @property
     def enabled(self) -> bool:
@@ -51,6 +75,22 @@ class MLAConfig:
     @property
     def enabled(self) -> bool:
         return self.kv_lora_rank > 0
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (DeepSeek-V3's ``rope_scaling``)."""
+
+    factor: float = 0.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @property
+    def enabled(self) -> bool:
+        return self.factor > 1.0
 
 
 @dataclass(frozen=True)
@@ -109,6 +149,7 @@ class ModelConfig:
     # ---- family sub-configs ---------------------------------------------------
     moe: MoEConfig = field(default_factory=MoEConfig)
     mla: MLAConfig = field(default_factory=MLAConfig)
+    yarn: YarnConfig = field(default_factory=YarnConfig)
     ssm: SSMConfig = field(default_factory=SSMConfig)
     rec: RecurrentConfig = field(default_factory=RecurrentConfig)
 
@@ -208,6 +249,10 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
             d_ff=32,
             n_dense_layers=min(cfg.moe.n_dense_layers, 1),
             dense_d_ff=128,
+            n_group=min(cfg.moe.n_group, 4),
+            topk_group=min(cfg.moe.topk_group, 2),
+            experts_held=min(cfg.moe.experts_held, 4),
+            expert_offset=0,
         )
     if cfg.mla.enabled:
         ch["mla"] = MLAConfig(

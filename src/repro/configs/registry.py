@@ -5,6 +5,7 @@ from __future__ import annotations
 from repro.configs.base import SHAPES, ModelConfig, ShapeConfig, applicable, reduced
 from repro.configs.deepseek_moe_16b import CONFIG as _deepseek_moe_16b
 from repro.configs.deepseek_v3_671b import CONFIG as _deepseek_v3_671b
+from repro.configs.deepseek_v3_ep32_5l import CONFIG as _deepseek_v3_ep32_5l
 from repro.configs.llava_next_mistral_7b import CONFIG as _llava_next_mistral_7b
 from repro.configs.mamba2_1_3b import CONFIG as _mamba2_1_3b
 from repro.configs.qwen3_8b import CONFIG as _qwen3_8b
@@ -31,12 +32,21 @@ ARCHS: dict[str, ModelConfig] = {
 }
 
 
+# Serving deployments cut from an architecture above: one chip's share of a
+# stated multi-chip deployment.  They resolve by name like an architecture
+# but stay outside the (arch x shape) grid.
+SHARES: dict[str, ModelConfig] = {c.name: c for c in (_deepseek_v3_ep32_5l,)}
+
+
 def get_arch(name: str) -> ModelConfig:
     if name.endswith("-smoke"):
         return reduced(get_arch(name[: -len("-smoke")]))
-    if name not in ARCHS:
-        raise KeyError(f"unknown arch {name!r}; known: {sorted(ARCHS)}")
-    return ARCHS[name]
+    if name in ARCHS:
+        return ARCHS[name]
+    if name in SHARES:
+        return SHARES[name]
+    raise KeyError(f"unknown arch {name!r}; known: "
+                   f"{sorted(ARCHS) + sorted(SHARES)}")
 
 
 def get_shape(name: str) -> ShapeConfig:

@@ -10,11 +10,13 @@ Three scaled-dot-product implementations:
 * ``pallas``     — the TPU kernel in ``repro.kernels.flash_attention`` (ops.py
                    wrapper); numerically validated against ``naive`` in tests.
 
-MLA (DeepSeek-V3) keeps a *compressed* KV cache (kv_lora + rope dims per
-token).  Decode supports two paths: ``absorb=False`` decompresses the cache
-every step (faithful to the algebraic definition — our paper-faithful
-baseline) and ``absorb=True`` folds the decompression matrices into the query
-and output projections (the optimized path; see EXPERIMENTS.md §Perf).
+MLA (DeepSeek-V3) keeps a *compressed* KV cache: one latent per token,
+``ckv ‖ k_pe`` (kv_lora + rope dims, 576 wide for DeepSeek-V3, the layout
+FlashMLA uses), so a paged pool stores one wide leaf per run of layers.
+Decode supports two paths: ``absorb=False`` decompresses the cache every
+step (faithful to the algebraic definition — our paper-faithful baseline)
+and ``absorb=True`` folds the decompression matrices into the query and
+output projections (the optimized path; see EXPERIMENTS.md §Perf).
 """
 
 from __future__ import annotations
@@ -23,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.layers import cdtype, rmsnorm_1d, rope_fwd
+from repro.models.layers import cdtype, rmsnorm_1d, rope_fwd, yarn_mscale
 
 NEG_INF = -1e30
 
@@ -45,10 +47,11 @@ def _mask_bias(q_pos, kv_pos, *, causal: bool, window: int):
 
 
 def sdpa_naive(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
-               softcap: float = 0.0):
+               softcap: float = 0.0, scale: float | None = None):
     """q: (B,Sq,Hq,hd); k: (B,Skv,Hkv,hd); v: (B,Skv,Hkv,hd_v).
 
-    hd_v may differ from hd (MLA). Returns (B,Sq,Hq,hd_v).
+    hd_v may differ from hd (MLA). Scores scale by ``scale``, by default
+    ``hd ** -0.5``.  Returns (B,Sq,Hq,hd_v).
     """
     B, Sq, Hq, hd = q.shape
     Hkv = k.shape[2]
@@ -60,7 +63,7 @@ def sdpa_naive(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
         kv_pos = jnp.arange(k.shape[1])
     qg = q.reshape(B, Sq, Hkv, G, hd)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32)
-    scores *= hd ** -0.5
+    scores *= hd ** -0.5 if scale is None else scale
     if softcap:
         scores = softcap * jnp.tanh(scores / softcap)
     scores += _mask_bias(q_pos, kv_pos, causal=causal, window=window)
@@ -70,7 +73,8 @@ def sdpa_naive(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
 
 
 def sdpa_blockwise(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
-                   chunk: int = 1024, softcap: float = 0.0):
+                   chunk: int = 1024, softcap: float = 0.0,
+                   scale: float | None = None):
     """Online-softmax attention, scanning KV in chunks (flash in XLA)."""
     B, Sq, Hq, hd = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
@@ -89,6 +93,7 @@ def sdpa_blockwise(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
     vs = v.reshape(B, nc, chunk, Hkv, hd_v).swapaxes(0, 1)
     kps = kv_pos.reshape(nc, chunk)
 
+    sm_scale = hd ** -0.5 if scale is None else scale
     m0 = jnp.full((B, Hkv, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, Hkv, G, Sq), jnp.float32)
     a0 = jnp.zeros((B, Sq, Hkv, G, hd_v), jnp.float32)
@@ -97,7 +102,7 @@ def sdpa_blockwise(q, k, v, *, causal=True, window=0, q_pos=None, kv_pos=None,
         m, l, acc = carry
         kc, vc, kp = inp
         s = jnp.einsum("bqkgh,bckh->bkgqc", qf, kc).astype(jnp.float32)
-        s *= hd ** -0.5
+        s *= sm_scale
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
         s += _mask_bias(q_pos, kp, causal=causal, window=window)
@@ -121,6 +126,9 @@ def sdpa(q, k, v, *, impl="naive", **kw):
         from repro.kernels.flash_attention import ops as fa_ops
 
         kw.pop("chunk", None)
+        scale = kw.pop("scale", None)
+        if scale is not None:       # the kernel scales by hd ** -0.5
+            q = q * jnp.asarray(scale * q.shape[-1] ** 0.5, q.dtype)
         return fa_ops.flash_attention(q, k, v, **kw)
     kw.pop("chunk", None)
     return sdpa_naive(q, k, v, **kw)
@@ -298,41 +306,59 @@ def init_mla(cfg: ModelConfig, key: jax.Array) -> dict:
     }
 
 
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(qk_nope + qk_rope) ** -0.5``, times YaRN's
+    ``mscale(factor, mscale_all_dim) ** 2`` when the rotary is scaled."""
+    m, y = cfg.mla, cfg.yarn
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    if y.enabled and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x, positions):
+    return rope_fwd(x, positions, cfg.rope_theta, yarn=cfg.yarn)
+
+
 def _mla_q(cfg: ModelConfig, p: dict, x, positions):
     m = cfg.mla
     cq = rmsnorm_1d(x @ p["wq_a"], p["q_norm"])
     q = jnp.einsum("bsr,rhk->bshk", cq, p["wq_b"])
     q_nope = q[..., : m.qk_nope_head_dim]
-    q_pe = rope_fwd(q[..., m.qk_nope_head_dim:], positions, cfg.rope_theta)
+    q_pe = _mla_rope(cfg, q[..., m.qk_nope_head_dim:], positions)
     return q_nope, q_pe
 
 
 def _mla_latent(cfg: ModelConfig, p: dict, x, positions):
+    """(B, S, kv_lora + rope): the normed latent and the rotated rope key
+    of each token, side by side."""
     m = cfg.mla
     ckv_full = x @ p["wkv_a"]
     ckv = rmsnorm_1d(ckv_full[..., : m.kv_lora_rank], p["kv_norm"])
     k_pe = ckv_full[..., m.kv_lora_rank:][:, :, None, :]  # single rope "head"
-    k_pe = rope_fwd(k_pe, positions, cfg.rope_theta)[:, :, 0, :]
-    return ckv, k_pe
+    k_pe = _mla_rope(cfg, k_pe, positions)[:, :, 0, :]
+    return jnp.concatenate([ckv, k_pe], axis=-1)
 
 
 def mla_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *, positions=None,
-            impl: str = "naive", chunk: int = 1024) -> jax.Array:
+            impl: str = "naive", chunk: int = 1024, lat=None) -> jax.Array:
     """Full-sequence MLA (train / prefill math, decompressed form)."""
     m = cfg.mla
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)
     q_nope, q_pe = _mla_q(cfg, p, x, positions)
-    ckv, k_pe = _mla_latent(cfg, p, x, positions)
+    if lat is None:
+        lat = _mla_latent(cfg, p, x, positions)
+    ckv, k_pe = lat[..., : m.kv_lora_rank], lat[..., m.kv_lora_rank:]
     k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["wk_b"])
     v = jnp.einsum("bsr,rhk->bshk", ckv, p["wv_b"])
     k_pe_h = jnp.broadcast_to(k_pe[:, :, None, :], q_pe.shape[:1] + (S,) + q_pe.shape[2:])
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, k_pe_h], axis=-1)
-    # sdpa scales by k.shape[-1] ** -0.5 == (qk_nope + qk_rope) ** -0.5 already.
     out = sdpa(q, k, v, impl=impl, causal=True, window=0,
-               q_pos=positions, kv_pos=positions, chunk=chunk)
+               q_pos=positions, kv_pos=positions, chunk=chunk,
+               scale=mla_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
 
@@ -340,9 +366,10 @@ def mla_prefill(cfg: ModelConfig, p: dict, x: jax.Array, *, impl="blockwise",
                 chunk: int = 1024, margin: int = 0):
     B, S, _ = x.shape
     positions = jnp.arange(S)
-    out = mla_fwd(cfg, p, x, positions=positions, impl=impl, chunk=chunk)
-    ckv, k_pe = _mla_latent(cfg, p, x, positions)
-    cache = _ring_cache_from_prefill({"ckv": ckv, "k_pe": k_pe}, S, S + margin)
+    lat = _mla_latent(cfg, p, x, positions)
+    out = mla_fwd(cfg, p, x, positions=positions, impl=impl, chunk=chunk,
+                  lat=lat)
+    cache = _ring_cache_from_prefill({"lat": lat}, S, S + margin)
     return out, cache
 
 
@@ -350,33 +377,35 @@ def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
                cache: dict, *, absorb: bool = True):
     """One-token MLA decode over the compressed cache.
 
-    cache: {"ckv": (B, Smax, kv_lora), "k_pe": (B, Smax, rope_dim)}.
+    cache: {"lat": (B, Smax, kv_lora + rope), "kv_pos": (Smax,)}.
     ``absorb=False`` decompresses the whole cache each step (baseline);
-    ``absorb=True`` runs attention in latent space (optimized).
+    ``absorb=True`` runs attention in latent space (optimized): the query
+    folded through ``wk_b`` meets the latent and the rope key in one
+    product over the whole latent width.
     """
     m = cfg.mla
-    B = x.shape[0]
-    cap = cache["ckv"].shape[1]
+    r = m.kv_lora_rank
+    cap = cache["lat"].shape[1]
     positions = jnp.full((1,), pos, jnp.int32)
     q_nope, q_pe = _mla_q(cfg, p, x, positions)          # (B,1,H,*)
-    ckv_new, k_pe_new = _mla_latent(cfg, p, x, positions)
+    lat_new = _mla_latent(cfg, p, x, positions)
     slot = jnp.asarray(pos, jnp.int32) % cap
-    ckv = jax.lax.dynamic_update_slice(cache["ckv"], ckv_new, (0, slot, 0))
-    k_pe = jax.lax.dynamic_update_slice(cache["k_pe"], k_pe_new, (0, slot, 0))
+    lat = jax.lax.dynamic_update_slice(cache["lat"], lat_new, (0, slot, 0))
     kv_pos = jax.lax.dynamic_update_slice(cache["kv_pos"], positions, (slot,))
-    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    scale = mla_scale(cfg)
     mask = jnp.where(kv_pos <= pos, 0.0, NEG_INF)[None, None, :]
 
     if absorb:
-        # score = (q_nope Wk_b^T) . ckv + q_pe . k_pe  — never decompress.
+        # score = [q_nope Wk_b^T ‖ q_pe] . [ckv ‖ k_pe] — never decompress.
         q_lat = jnp.einsum("bqhk,rhk->bqhr", q_nope, p["wk_b"])
-        s = (jnp.einsum("bqhr,bsr->bhqs", q_lat, ckv)
-             + jnp.einsum("bqhk,bsk->bhqs", q_pe, k_pe)).astype(jnp.float32)
+        q_cat = jnp.concatenate([q_lat, q_pe.astype(q_lat.dtype)], axis=-1)
+        s = jnp.einsum("bqhr,bsr->bhqs", q_cat, lat).astype(jnp.float32)
         s = s[:, :, 0, :] * scale + mask
         probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)       # (B,H,S)
-        o_lat = jnp.einsum("bhs,bsr->bhr", probs, ckv)
+        o_lat = jnp.einsum("bhs,bsr->bhr", probs, lat[..., :r])
         out = jnp.einsum("bhr,rhk->bhk", o_lat, p["wv_b"])[:, None]
     else:
+        ckv, k_pe = lat[..., :r], lat[..., r:]
         k_nope = jnp.einsum("bsr,rhk->bshk", ckv, p["wk_b"])
         v = jnp.einsum("bsr,rhk->bshk", ckv, p["wv_b"])
         s = (jnp.einsum("bqhk,bshk->bhqs", q_nope, k_nope)
@@ -384,16 +413,16 @@ def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
         s = s[:, :, 0, :] * scale + mask
         probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
         out = jnp.einsum("bhs,bshk->bhk", probs, v)[:, None]
-    new_cache = {"ckv": ckv, "k_pe": k_pe, "kv_pos": kv_pos}
+    new_cache = {"lat": lat, "kv_pos": kv_pos}
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"]), new_cache
 
 
 def mla_cache_spec(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     m = cfg.mla
     dt = cdtype(cfg)
+    width = m.kv_lora_rank + m.qk_rope_head_dim
     return {
-        "ckv": jax.ShapeDtypeStruct((batch, max_len, m.kv_lora_rank), dt),
-        "k_pe": jax.ShapeDtypeStruct((batch, max_len, m.qk_rope_head_dim), dt),
+        "lat": jax.ShapeDtypeStruct((batch, max_len, width), dt),
         "kv_pos": jax.ShapeDtypeStruct((max_len,), jnp.int32),
     }
 

@@ -7,8 +7,11 @@ run in the config compute dtype (bfloat16 for the full-size configs).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 
@@ -51,13 +54,40 @@ def rmsnorm_1d(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 # Rotary position embeddings
 # ---------------------------------------------------------------------------
 
+def yarn_inv_freq(dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN's rotary frequencies (DeepSeek-V3's rope_scaling): the
+    extrapolated ``theta**(-2i/dim)`` on the fast dims, the same over
+    ``factor`` on the slow ones, a linear ramp between the correction dims
+    of ``beta_fast`` and ``beta_slow`` rotations over the original
+    context."""
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    # 0 on the fast dims (extrapolated), 1 on the slow (interpolated)
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def rope_fwd(x: jax.Array, positions: jax.Array, theta: float,
-             rope_pct: float = 1.0) -> jax.Array:
+             rope_pct: float = 1.0, yarn=None) -> jax.Array:
     """Apply RoPE.
 
     x: (..., S, H, hd), positions: broadcastable to (..., S).
     ``rope_pct`` < 1 rotates only the leading fraction of head dims
-    (stablelm-style partial rotary).
+    (stablelm-style partial rotary); an enabled ``yarn``
+    (``YarnConfig``) takes its frequencies from ``yarn_inv_freq``.
     """
     hd = x.shape[-1]
     rot = int(hd * rope_pct)
@@ -65,7 +95,10 @@ def rope_fwd(x: jax.Array, positions: jax.Array, theta: float,
     if rot == 0:
         return x
     x_rot, x_pass = x[..., :rot], x[..., rot:]
-    freqs = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    if yarn is not None and yarn.enabled:
+        freqs = jnp.asarray(yarn_inv_freq(rot, theta, yarn))
+    else:
+        freqs = theta ** (-jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, rot/2)
     angles = angles[..., None, :]                              # (..., S, 1, rot/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)

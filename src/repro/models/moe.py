@@ -21,10 +21,23 @@ train_4k), so we use a *sort-based capacity dispatch* inside ``shard_map``:
 Activations enter replicated over "model" (standard TP layout), so no
 all-to-all is required.  The router and its aux load-balancing loss are
 computed identically on every shard.
+
+A configuration with ``moe.experts_held`` is one chip's share of an
+expert-parallel deployment instead (``held_moe_fwd``): the layer routes
+every token over all experts with the published router (``route``),
+computes every routed pair whose expert is held here — sorted by expert,
+one grouped product (``jax.lax.ragged_dot``) per weight, no capacity and
+nothing dropped; decode, one token a lane, takes a masked product over
+the held experts instead — and adds the shared expert.  What the experts held on
+other chips would add is not computed: on one chip the layer runs without
+its exchange.  Serving folds every lane of a vmapped decode into one
+routed batch (``custom_vmap``), so the held experts' weights are read once
+per step, not once per lane.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
 
@@ -269,3 +282,155 @@ def moe_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *, mesh,
         shared_cfg = dataclasses.replace(cfg, mlp_kind="silu_glu")
         out = out + mlp_fwd(shared_cfg, p["shared"], x)
     return out, aux
+
+
+# ---------------------------------------------------------------------------
+# One chip's share of an expert-parallel layer: held experts, dropless
+# ---------------------------------------------------------------------------
+
+def route(cfg: ModelConfig, p: dict, x2d: jax.Array):
+    """(T, D) -> (experts (T, k) int32, weights (T, k) float32).
+
+    DeepSeek-V3's noaux_tc: scores are the sigmoid of the float32 router
+    logits.  Experts are ranked by score plus the selection bias
+    (``score_bias``), among the best ``topk_group`` groups, a group scored
+    by the sum of its two best; a chosen expert's weight is its unbiased
+    score over the chosen scores' sum, times ``routed_scaling_factor``."""
+    m = cfg.moe
+    logits = x2d.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    sel = scores + p["score_bias"]
+    if m.n_group > 1:
+        T, E = sel.shape
+        grouped = sel.reshape(T, m.n_group, E // m.n_group)
+        gscore = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, gbest = jax.lax.top_k(gscore, m.topk_group)
+        keep = jnp.any(gbest[:, :, None] == jnp.arange(m.n_group), axis=1)
+        sel = jnp.where(jnp.repeat(keep, E // m.n_group, axis=1), sel,
+                        -jnp.inf)
+    _, top_e = jax.lax.top_k(sel, m.top_k)
+    w = jnp.take_along_axis(scores, top_e, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * m.routed_scaling_factor
+    return top_e.astype(jnp.int32), w
+
+
+def init_held_moe(cfg: ModelConfig, key: jax.Array) -> dict:
+    """The router over every expert, the held experts' weights and the
+    shared expert.  Expert ``e`` is drawn from key ``e`` of a split over
+    all experts, so every share draws its experts as the uncut layer
+    would."""
+    m = cfg.moe
+    dt = cdtype(cfg)
+    D, E, F = cfg.d_model, m.num_experts, m.d_ff
+    ks = jax.random.split(key, 5)
+    s_in, s_ff = D ** -0.5, F ** -0.5
+
+    def expert(k):
+        kg, ku, kd = jax.random.split(k, 3)
+        return ((jax.random.normal(kg, (D, F)) * s_in).astype(dt),
+                (jax.random.normal(ku, (D, F)) * s_in).astype(dt),
+                (jax.random.normal(kd, (F, D)) * s_ff).astype(dt))
+
+    held = jax.random.split(ks[1], E)[
+        m.expert_offset:m.expert_offset + m.experts_held]
+    wg, wu, wd = jax.vmap(expert)(held)
+    p = {"router": (jax.random.normal(ks[0], (D, E)) * s_in).astype(
+             jnp.float32),
+         "w_gate": wg, "w_up": wu, "w_down": wd,
+         "score_bias": jax.random.normal(ks[2], (E,)) * 0.1}
+    if m.num_shared_experts:
+        p["shared"] = init_mlp(_shared_cfg(cfg), ks[4], D,
+                               F * m.num_shared_experts)
+    return p
+
+
+def _shared_cfg(cfg: ModelConfig) -> ModelConfig:
+    return dataclasses.replace(cfg, mlp_kind="silu_glu")
+
+
+def _held_experts(cfg: ModelConfig, p: dict, x2d: jax.Array) -> jax.Array:
+    """Every routed pair whose expert is held here, weighted: (T, D)."""
+    m = cfg.moe
+    T, D = x2d.shape
+    k, n = m.top_k, m.experts_held
+    top_e, w = route(cfg, p, x2d)
+    local = top_e - m.expert_offset
+    held = (local >= 0) & (local < n)
+    flat = jnp.where(held, local, n).reshape(-1)                # (T*k,)
+    order = jnp.argsort(flat, stable=True)
+    # held pairs sort first, grouped by expert: rows past the groups'
+    # total lie outside every group and are never read
+    rows = min(T * k, T * n)
+    order = order[:rows]
+    sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+    xs = x2d[order // k]                                        # (rows, D)
+    h = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+    ys = jax.lax.ragged_dot(jax.nn.silu(h) * u, p["w_down"], sizes)
+    # each token gathers its own pairs back in choice order and sums them
+    # in float32, so its output never depends on the other tokens
+    slot = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32))
+    mine = ys[jnp.where(held.reshape(-1), slot, 0)].reshape(T, k, D)
+    wt = jnp.where(held, w, 0.0)
+    out = jnp.sum(jnp.where(held[..., None], mine.astype(jnp.float32), 0.0)
+                  * wt[..., None], axis=1)
+    return out.astype(x2d.dtype)
+
+
+def _held_experts_masked(cfg: ModelConfig, p: dict,
+                         x2d: jax.Array) -> jax.Array:
+    """Every token against every held expert, each pair weighted by its
+    routing weight, zero where the token did not choose the expert: (T, D).
+    The same pairs as ``_held_experts`` (which pads to ``T * held`` rows
+    too), with no sort or gather, and each expert's weights read once in
+    place; its cost does not depend on how the tokens route."""
+    m = cfg.moe
+    top_e, w = route(cfg, p, x2d)
+    local = top_e - m.expert_offset
+    wt = jnp.sum(jnp.where(local[..., None] == jnp.arange(m.experts_held),
+                           w[..., None], 0.0), axis=1)           # (T, held)
+    h = jnp.einsum("td,edf->etf", x2d, p["w_gate"])
+    u = jnp.einsum("td,edf->etf", x2d, p["w_up"])
+    ys = jnp.einsum("etf,efd->etd", jax.nn.silu(h) * u, p["w_down"])
+    out = jnp.sum(ys.astype(jnp.float32) * wt.T[..., None], axis=0)
+    return out.astype(x2d.dtype)
+
+
+def _held_moe(cfg: ModelConfig, p: dict, x: jax.Array, *,
+              masked: bool = False) -> jax.Array:
+    B, S, D = x.shape
+    experts = _held_experts_masked if masked else _held_experts
+    out = experts(cfg, p, x.reshape(B * S, D)).reshape(B, S, D)
+    if cfg.moe.num_shared_experts:
+        out = out + mlp_fwd(_shared_cfg(cfg), p["shared"], x)
+    return out
+
+
+def held_moe_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *,
+                 fold: bool = False, masked: bool = False) -> jax.Array:
+    """x: (B, S, D) -> (B, S, D), this chip's share of the expert layer.
+
+    ``fold=True`` (serving): under ``vmap`` the mapped axis is folded into
+    the batch, so all lanes route as one batch through one grouped
+    product per weight.  ``masked=True`` (decode, one token a lane) takes
+    the masked product over the held experts in place of the grouped one."""
+    body = partial(_held_moe, cfg, masked=masked)
+    if not fold:
+        return body(p, x)
+
+    @jax.custom_batching.custom_vmap
+    def layer(p, x):
+        return body(p, x)
+
+    @layer.def_vmap
+    def _layer_vmap(axis_size, in_batched, p, x):
+        p_batched, x_batched = in_batched
+        if any(jax.tree.leaves(p_batched)) or not x_batched:
+            axes = jax.tree.map(lambda b: 0 if b else None, p_batched)
+            return jax.vmap(body, in_axes=(axes, 0 if x_batched else None))(
+                p, x), True
+        y = layer(p, x.reshape((axis_size * x.shape[1],) + x.shape[2:]))
+        return y.reshape(x.shape), True
+
+    return layer(p, x)

@@ -29,7 +29,8 @@ from repro.models import rglru, ssm
 from repro.models.layers import (cross_entropy, embed_fwd, init_embed,
                                  init_mlp, init_norm, lm_head_fwd, mlp_fwd,
                                  norm_fwd)
-from repro.models.moe import init_moe, moe_fwd
+from repro.models.moe import held_moe_fwd, init_held_moe, init_moe, moe_fwd
+from repro.obs.scope import device_scope
 
 
 @dataclass(frozen=True)
@@ -95,7 +96,8 @@ def init_block(cfg: ModelConfig, key: jax.Array, spec: BlockSpec) -> dict:
         if spec.ffn == "mlp":
             p["mlp"] = init_mlp(cfg, k2, cfg.d_model, spec.d_ff)
         elif spec.ffn == "moe":
-            p["moe"] = init_moe(cfg, k3)
+            p["moe"] = (init_held_moe(cfg, k3) if cfg.moe.experts_held
+                        else init_moe(cfg, k3))
         else:
             raise ValueError(spec.ffn)
     return p
@@ -120,15 +122,16 @@ def block_apply(cfg: ModelConfig, p: dict, spec: BlockSpec, x: jax.Array, *,
             mix, new_cache = attn.gqa_decode(
                 cfg, p["attn"], h, pos, cache, window=spec.window)
     elif spec.mixer == "mla":
-        if mode == "train":
-            mix = attn.mla_fwd(cfg, p["attn"], h, impl=impl)
-        elif mode == "prefill":
-            mix, new_cache = attn.mla_prefill(
-                cfg, p["attn"], h, impl=impl, chunk=prefill_chunk,
-                margin=cache_margin)
-        else:
-            mix, new_cache = attn.mla_decode(
-                cfg, p["attn"], h, pos, cache, absorb=mla_absorb)
+        with device_scope("mla"):
+            if mode == "train":
+                mix = attn.mla_fwd(cfg, p["attn"], h, impl=impl)
+            elif mode == "prefill":
+                mix, new_cache = attn.mla_prefill(
+                    cfg, p["attn"], h, impl=impl, chunk=prefill_chunk,
+                    margin=cache_margin)
+            else:
+                mix, new_cache = attn.mla_decode(
+                    cfg, p["attn"], h, pos, cache, absorb=mla_absorb)
     elif spec.mixer == "rec":
         if mode == "train":
             mix = rglru.rec_block_fwd(cfg, p["rec"], h, impl=impl)
@@ -150,6 +153,11 @@ def block_apply(cfg: ModelConfig, p: dict, spec: BlockSpec, x: jax.Array, *,
         h2 = norm_fwd(cfg, p["norm2"], x)
         if spec.ffn == "mlp":
             x = x + mlp_fwd(cfg, p["mlp"], h2)
+        elif cfg.moe.experts_held:
+            with device_scope("moe"):
+                x = x + held_moe_fwd(cfg, p["moe"], h2,
+                                     fold=mode != "train",
+                                     masked=mode == "decode")
         else:
             out, aux = moe_fwd(cfg, p["moe"], h2, mesh=mesh, dp_axes=dp_axes,
                                dispatch=cfg.moe_dispatch)

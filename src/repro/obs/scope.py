@@ -10,6 +10,9 @@ profile it is an inactive TraceMe of a microsecond or two.  Given
 same name and labels, which ``with ... as`` receives; without one it
 records no obs span and ``as`` receives ``None``.
 
+``device_scope(name)`` names the device operations a block of traced
+code emits (``jax.named_scope("funky." + name)``).
+
 A span whose start and end fall on different threads (a queue wait) cannot
 be scoped, and is recorded through the obs ``Tracer`` alone.
 """
@@ -18,11 +21,19 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from .tracer import Span
 
 PREFIX = "funky."
+
+
+def device_scope(name: str):
+    """``jax.named_scope("funky." + name)``: inside a traced function, the
+    device operations it emits carry the name, so a profile attributes
+    them to this part of the model."""
+    return jax.named_scope(PREFIX + name)
 
 
 def span(name: str, parent: Optional[Span] = None,

@@ -96,7 +96,7 @@ from repro.serve.kvcache import (BlockPool, _is_pos_leaf,
                                  compact_pool, extract_pool_pages,
                                  extract_written_page, gather_lane_cache,
                                  init_caches_from_specs, install_pool_pages,
-                                 merged_pool_leaves,
+                                 latent_bytes_per_token, merged_pool_leaves,
                                  pool_specs_from_lane_cache, scatter_pages,
                                  scatter_prefill, scrub_pages,
                                  token_axes_from_lengths)
@@ -120,6 +120,12 @@ M_QUEUE_WAIT_US = "queue_wait_us"
 # k/v pool leaves stored with (heads, head_dim) merged, and their bytes
 M_KV_MERGED_LEAVES = "kv_pool_merged_leaves"
 M_KV_MERGED_BYTES = "kv_pool_merged_bytes"
+# experts this chip computes and experts the router chooses among (0 and 0
+# for a dense model), and the bytes of MLA latent cache a token takes over
+# every layer (0 without MLA)
+M_MOE_HELD = "moe_experts_held"
+M_MOE_ROUTED = "moe_experts_routed"
+M_KV_LATENT_BYTES = "kv_latent_bytes_per_token"
 
 
 @dataclass(frozen=True)
@@ -601,6 +607,16 @@ class ContinuousBatchingEngine:
                             engine=self.engine_id).set(len(merged))
         self.registry.gauge(M_KV_MERGED_BYTES, service=self.service,
                             engine=self.engine_id).set(self.kv_merged_bytes)
+        moe = self.cfg.moe
+        self.registry.gauge(M_MOE_HELD, service=self.service,
+                            engine=self.engine_id).set(
+            moe.experts_held or moe.num_experts)
+        self.registry.gauge(M_MOE_ROUTED, service=self.service,
+                            engine=self.engine_id).set(moe.num_experts)
+        self.kv_latent_bytes_per_token = latent_bytes_per_token(lane_abs)
+        self.registry.gauge(M_KV_LATENT_BYTES, service=self.service,
+                            engine=self.engine_id).set(
+            self.kv_latent_bytes_per_token)
         toks_abs = jax.ShapeDtypeStruct((B, 1), jnp.int32)
         pos_abs = jax.ShapeDtypeStruct((B,), jnp.int32)
         bt_abs = jax.ShapeDtypeStruct((B, max_blocks), jnp.int32)

@@ -28,8 +28,9 @@ virtualizes that memory behind an indirection layer, PagedAttention-style:
 Every leaf of the device pool has the page axis as axis 0, matching the
 ``BufferTable``'s page-granular dirtiness: evict/checkpoint serialize only
 the pages written since the last sync plus the (tiny) block table.  A k/v
-leaf stores its trailing ``(heads, head_dim)`` as one axis: a TPU then lays
-the pool out page-major, one page one contiguous block, for any head_dim.
+leaf stores its trailing ``(heads, head_dim)`` as one axis, an MLA latent
+leaf its whole per-token residue as one axis of whole lanes: a TPU then
+lays the pool out page-major, one page one contiguous block.
 """
 
 from __future__ import annotations
@@ -58,9 +59,13 @@ def init_caches_from_specs(specs):
     return jax.tree_util.tree_map_with_path(mk, specs)
 
 
-def _is_pos_leaf(path) -> bool:
+def _leaf_name(path) -> str:
     names = [k.key for k in path if hasattr(k, "key")]
-    return bool(names) and names[-1] == "kv_pos"
+    return names[-1] if names else ""
+
+
+def _is_pos_leaf(path) -> bool:
+    return _leaf_name(path) == "kv_pos"
 
 
 def pages_for_tokens(n_tokens: int, page_size: int) -> int:
@@ -236,7 +241,13 @@ class BlockPool:
 # to 128 lanes unless it puts the page axis minor-most instead; then one
 # page is scattered across the whole leaf, a gather of pages crawls and
 # every page scatter relayouts the pool.  Merged, the page axis stays
-# major.  The merge pads nothing; position leaves keep their shape.
+# major.  An MLA latent leaf (``lat``, per token ``(layers, 1, latent)``)
+# merges all of ``rest``: with the layer axis left beside the latent, a
+# page scatter relayouts the leaf to put the page's tokens second-minor
+# and back.  It is also padded to whole lanes: DeepSeek-V3's latent is 576
+# wide, and with one layer to a leaf the chip would put the page axis
+# minor-most (a pool of 20,480 pages pads nothing there).  The k/v merge
+# pads nothing; position leaves keep their shape.
 
 def token_axes_from_lengths(cache_a, cache_b, len_a: int, len_b: int, *,
                             exact: bool = True):
@@ -278,12 +289,32 @@ def _lane_rest(leaf, axis):
     return leaf.shape[:axis] + leaf.shape[axis + 1:]
 
 
+LANES = 128        # a TPU tile's minor extent
+
+
 def _stored_rest(path, rest):
     """Per-token shape a pool leaf stores: ``rest`` with its last two axes
-    merged, except for position leaves and leaves with fewer axes."""
+    merged; an MLA latent (``lat``) leaf's whole ``rest`` in one axis,
+    padded to a multiple of ``LANES``; position leaves and leaves with
+    fewer axes as they are."""
     if _is_pos_leaf(path) or len(rest) < 2:
         return rest
+    if _leaf_name(path) == "lat":
+        return (-(-math.prod(rest) // LANES) * LANES,)
     return rest[:-2] + (rest[-2] * rest[-1],)
+
+
+def _to_pool(vals, pool_leaf):
+    """Whole pages ``(n, page_size, *per-token)``, in a lane cache's
+    per-token shape or the pool's -> ``(n, *pool_leaf.shape[1:])``, the
+    stored axis zero-padded where the pool pads it."""
+    tail = pool_leaf.shape[1:]
+    width = vals.size // (vals.shape[0] * math.prod(tail[:-1]))
+    vals = vals.reshape(vals.shape[:1] + tail[:-1] + (width,))
+    if width < tail[-1]:
+        vals = jnp.pad(vals, [(0, 0)] * (vals.ndim - 1)
+                       + [(0, tail[-1] - width)])
+    return vals
 
 
 def pool_specs_from_lane_cache(lane_cache_abs, token_axes, num_pages: int,
@@ -301,10 +332,22 @@ def pool_specs_from_lane_cache(lane_cache_abs, token_axes, num_pages: int,
 
 
 def merged_pool_leaves(pool, lane_cache_abs):
-    """The pool leaves stored with two per-token axes merged into one."""
+    """The pool leaves stored with per-token axes merged into one."""
     return [p for p, l in zip(jax.tree.leaves(pool),
                               jax.tree.leaves(lane_cache_abs))
-            if p.ndim == l.ndim]
+            if p.ndim <= l.ndim]
+
+
+def latent_bytes_per_token(lane_cache_abs) -> int:
+    """Bytes one token takes in the MLA latent (``lat``) leaves of a lane
+    cache, over every layer; 0 for a model without MLA."""
+    def per_token(path, leaf):
+        if _leaf_name(path) != "lat":
+            return 0
+        return leaf.size // leaf.shape[-2] * leaf.dtype.itemsize
+
+    return sum(jax.tree.leaves(jax.tree_util.tree_map_with_path(
+        per_token, lane_cache_abs)))
 
 
 # ---------------------------------------------------------------------------
@@ -332,7 +375,11 @@ def gather_lane_cache(pool, block_row, lane_cache_abs, token_axes, *,
         rows = (safe[:, None] * page_size
                 + jnp.arange(page_size, dtype=safe.dtype)).reshape(cap)
         flat = leaf.reshape((-1,) + leaf.shape[2:])[rows]
-        flat = flat.reshape((cap,) + _lane_rest(lane, axis))
+        rest = _lane_rest(lane, axis)
+        width = math.prod(rest) // math.prod(flat.shape[1:-1])
+        if width < flat.shape[-1]:               # the pool's lane padding
+            flat = flat[..., :width]
+        flat = flat.reshape((cap,) + rest)
         if _is_pos_leaf(path):
             valid = jnp.repeat(block_row >= 0, page_size)
             flat = jnp.where(
@@ -363,8 +410,7 @@ def scatter_pages(pool, phys_ids, pages):
     out-of-range ids (inactive lanes) are dropped.  Active lanes own
     disjoint pages, so the scatter is conflict-free."""
     return jax.tree.map(
-        lambda pl, pg: pl.at[phys_ids].set(
-            pg.reshape(pg.shape[:1] + pl.shape[1:]), mode="drop"),
+        lambda pl, pg: pl.at[phys_ids].set(_to_pool(pg, pl), mode="drop"),
         pool, pages)
 
 
@@ -385,7 +431,8 @@ def scatter_prefill(pool, page_ids, pf_cache, token_axes, *,
                              jnp.int32) if _is_pos_leaf(path)
                     else jnp.zeros((pad,) + vals.shape[1:], vals.dtype))
             vals = jnp.concatenate([vals, fill])
-        vals = vals.reshape((n_pp,) + pool_leaf.shape[1:])
+        vals = _to_pool(vals.reshape((n_pp, page_size) + vals.shape[1:]),
+                        pool_leaf)
         return pool_leaf.at[page_ids].set(vals)
 
     return jax.tree_util.tree_map_with_path(sc, pool, pf_cache, token_axes)
@@ -460,4 +507,8 @@ def apply_block_table_delta(block_table, delta):
         new_row = jnp.where(s < 0, row, new_row)
         return bt.at[s_safe].set(new_row)
 
-    return jax.lax.fori_loop(0, delta.shape[0], body, block_table)
+    # loop to the last row in use only: each iteration costs microseconds
+    # on the chip, and the padding rows after it change nothing
+    used = jnp.where(delta[:, 0] >= 0,
+                     jnp.arange(1, delta.shape[0] + 1, dtype=jnp.int32), 0)
+    return jax.lax.fori_loop(0, jnp.max(used), body, block_table)

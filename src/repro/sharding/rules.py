@@ -224,7 +224,7 @@ class ShardingRules:
                     tp_h = self._tp(leaf.shape[3])
                     tp_s = self._tp(leaf.shape[2]) if tp_h is None else None
                     rest = [tp_s, tp_h, None]
-                elif name == "ckv" and leaf.ndim == 4 and bdim is None:
+                elif name == "lat" and leaf.ndim == 4 and bdim is None:
                     # MLA latent cache (L, B, cap, r) at batch=1: shard cap
                     rest = [self._tp(leaf.shape[2]), None]
                 elif name == "ssm_state" and leaf.ndim == 5:
@@ -238,10 +238,8 @@ class ShardingRules:
                     rest = [None, self._tp(leaf.shape[3])]
                 elif name == "conv_state" and leaf.ndim == 4:
                     rest = [None, self._tp(leaf.shape[3])]
-                elif name == "ckv" and leaf.ndim == 4:
+                elif name == "lat" and leaf.ndim == 4:
                     # (L, B, cap, r): replicate r
-                    rest = [None, None]
-                elif name == "k_pe" and leaf.ndim == 4:
                     rest = [None, None]
                 return P(None, bdim, *rest)
             return P(*([None] * leaf.ndim))

@@ -9,7 +9,8 @@ import pytest
 
 from repro.core.state import BufferState, BufferTable, tree_bytes
 from repro.models.attention import _INVALID_POS
-from repro.serve.kvcache import (BlockPool, BlockPoolError, cache_bytes,
+from repro.serve.kvcache import (BlockPool, BlockPoolError,
+                                 apply_block_table_delta, cache_bytes,
                                  compact_pool, extract_pool_pages,
                                  extract_written_page, gather_lane_cache,
                                  install_pool_pages, merged_pool_leaves,
@@ -474,6 +475,34 @@ def _pool_value(n_pages=4, ps=2):
     return {"k": jnp.arange(n_pages * ps * 3, dtype=jnp.float32
                             ).reshape(n_pages, ps, 3),
             "kv_pos": jnp.zeros((n_pages, ps), jnp.int32)}
+
+
+@pytest.mark.parametrize("used", [0, 1, 5, 24])
+def test_block_table_delta_applies_rows_in_order(used):
+    """The delta rows apply in order, a clear before a remap of the same
+    slot included; padding rows, at the end or between used rows, change
+    nothing."""
+    rng = np.random.default_rng(used)
+    slots, blocks, width = 6, 10, 32
+    bt = rng.integers(-1, 50, (slots, blocks)).astype(np.int32)
+    delta = np.full((width, 3), -1, np.int32)
+    for i in range(used):
+        if i % 7 == 3:
+            continue                      # a padding row between used ones
+        lp = -1 if i % 5 == 4 else int(rng.integers(0, blocks))
+        delta[i] = (int(rng.integers(0, slots)), lp,
+                    int(rng.integers(-1, 50)))
+    want = bt.copy()
+    for s, lp, v in delta:
+        if s < 0:
+            continue
+        if lp < 0:
+            want[s] = -1
+        else:
+            want[s, lp] = v
+    got = jax.jit(apply_block_table_delta)(jnp.asarray(bt),
+                                           jnp.asarray(delta))
+    np.testing.assert_array_equal(np.asarray(got), want)
 
 
 def test_paged_buffer_evicts_only_dirty_pages():

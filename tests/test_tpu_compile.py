@@ -98,10 +98,13 @@ def test_dense_prefill_with_headroom_compiles_for_v5e(one_chip):
 
 # (layers, Hkv, head_dim) of paged k/v pool leaves: the chat cells' models
 # and a head_dim of 64; the cells' pool of 576 pages of 8 tokens serves 8
-# lanes and prompts of up to 512 tokens
+# lanes and prompts of up to 512 tokens.  An MLA model's lane cache is the
+# program's own: one dense and four expert layers, each a latent of 576,
+# in the reasoning cell's pool of 64 lanes of 320 pages.
 POOL = {"stablelm-3b": (32, 32, 80), "yi-9b-24l": (24, 4, 128),
-        "hd64": (32, 8, 64)}
+        "hd64": (32, 8, 64), "deepseek-v3-ep32-5l": "mla"}
 POOL_PAGES, PAGE, PROMPT, LANES = 576, 8, 512, 8
+PAGES_OF = {"deepseek-v3-ep32-5l": 64 * 320}
 
 
 def _lane_cache(layers, heads, hd, cap):
@@ -109,6 +112,20 @@ def _lane_cache(layers, heads, hd, cap):
     kv = jax.ShapeDtypeStruct((layers, 1, cap, heads, hd), BF16)
     return {"k": kv, "v": kv,
             "kv_pos": jax.ShapeDtypeStruct((layers, cap), I32)}
+
+
+def _lane_cache_of(model):
+    if POOL[model] == "mla":
+        from repro.models.transformer import lm_cache_specs
+
+        return functools.partial(lm_cache_specs, get_arch(model), 1)
+    return functools.partial(_lane_cache, *POOL[model])
+
+
+def _stored_leaves(pool):
+    """Every pool leaf but the positions: the k/v or latent leaves."""
+    return [leaf for path, leaf in jax.tree_util.tree_flatten_with_path(
+        pool)[0] if path[-1].key != "kv_pos"]
 
 
 def _whole_leaf_copies(hlo: str, leaf) -> list:
@@ -129,23 +146,24 @@ def _whole_leaf_copies(hlo: str, leaf) -> list:
 
 @pytest.mark.parametrize("model", sorted(POOL))
 def test_paged_pool_is_page_major_for_v5e(model, one_chip):
-    """The chip's default layout keeps each k/v pool leaf's page axis
-    major, so a page is one contiguous block, and the programs that write
-    pages (decode's page scatter, compaction, admission) update the
+    """The chip's default layout keeps each k/v or latent pool leaf's page
+    axis major, so a page is one contiguous block, and the programs that
+    write pages (decode's page scatter, compaction, admission) update the
     donated pool in place, with no relayout of a whole leaf."""
     from jax.experimental.layout import Layout
 
-    L, H, hd = POOL[model]
-    lane = functools.partial(_lane_cache, L, H, hd)
+    lane = _lane_cache_of(model)
     axes = token_axes_from_lengths(lane(PROMPT // 4), lane(PROMPT),
                                    PROMPT // 4, PROMPT)
-    pool = pool_specs_from_lane_cache(lane(PROMPT), axes, POOL_PAGES, PAGE)
+    pages_n = PAGES_OF.get(model, POOL_PAGES)
+    pool = pool_specs_from_lane_cache(lane(PROMPT), axes, pages_n, PAGE)
     dev = next(iter(one_chip.device_set))
-    for name in ("k", "v"):
+    stored = _stored_leaves(pool)
+    assert stored
+    for leaf in stored:
         layout = Layout.from_pjrt_layout(dev.client.get_default_layout(
-            pool[name].dtype, pool[name].shape, dev))
-        assert layout.major_to_minor[0] == 0, (name, pool[name].shape,
-                                               layout)
+            leaf.dtype, leaf.shape, dev))
+        assert layout.major_to_minor[0] == 0, (leaf.shape, layout)
 
     def on_chip(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -153,7 +171,7 @@ def test_paged_pool_is_page_major_for_v5e(model, one_chip):
     pool_arg = jax.tree.map(lambda l: on_chip(l.shape, l.dtype), pool)
     pages = jax.tree.map(lambda l: on_chip((LANES,) + l.shape[1:], l.dtype),
                          pool)
-    ids = on_chip((POOL_PAGES,), I32)
+    ids = on_chip((pages_n,), I32)
     programs = {
         "scatter_pages": (scatter_pages, (pool_arg, on_chip((LANES,), I32),
                                           pages)),
@@ -167,4 +185,5 @@ def test_paged_pool_is_page_major_for_v5e(model, one_chip):
     }
     for prog, (fn, args) in programs.items():
         hlo = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().as_text()
-        assert not _whole_leaf_copies(hlo, pool["k"]), prog
+        for leaf in stored:
+            assert not _whole_leaf_copies(hlo, leaf), (prog, leaf.shape)
