@@ -21,6 +21,10 @@ output projections (the optimized path; see EXPERIMENTS.md §Perf).
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -186,6 +190,71 @@ def gqa_fwd(cfg: ModelConfig, p: dict, x: jax.Array, *, window: int | None = Non
 _INVALID_POS = jnp.int32(2**30)  # masked-out slot sentinel (kv_pos > q_pos)
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("stack", "layer"),
+                   meta_fields=("shape", "axis", "flat"))
+@dataclasses.dataclass(frozen=True)
+class PagedLeaf:
+    """One leaf of a lane's cache as read from a paged KV pool.
+
+    ``stack`` holds the lane's rows of every layer of a layer-scanned
+    stack, token first: ``(cap, layers, *merged)``, or where ``flat`` all
+    layers side by side in one axis of whole lanes (an MLA latent).
+    ``shape`` is one layer's dense cache leaf, its token axis ``axis``.
+    The layer scan sets ``layer``; the attention reads that layer's rows
+    with ``rows()`` and returns only the new token's row, which the caller
+    writes into the pool.
+    """
+
+    stack: jax.Array
+    shape: tuple
+    axis: int
+    flat: bool
+    layer: jax.Array = None
+
+    def rows(self):
+        """This layer's rows of the lane's cache, in the dense cache's
+        layout, sliced from ``stack``."""
+        cap = self.shape[self.axis]
+        rest = self.shape[:self.axis] + self.shape[self.axis + 1:]
+        if self.flat:
+            width = math.prod(rest)
+            got = jax.lax.dynamic_slice_in_dim(self.stack, self.layer * width,
+                                               width, axis=1)
+        else:
+            got = jax.lax.dynamic_index_in_dim(self.stack, self.layer, 1,
+                                               keepdims=False)
+        return jnp.moveaxis(got.reshape((cap,) + rest), 0, self.axis)
+
+
+def _is_paged_leaf(x) -> bool:
+    return isinstance(x, PagedLeaf)
+
+
+def is_paged(cache) -> bool:
+    """Whether a cache pytree is a paged view (``PagedLeaf`` leaves)."""
+    return any(map(_is_paged_leaf,
+                   jax.tree.leaves(cache, is_leaf=_is_paged_leaf)))
+
+
+def at_layer(cache, layer):
+    """A paged view whose leaves read layer ``layer`` of their stack."""
+    return jax.tree.map(lambda c: dataclasses.replace(c, layer=layer), cache,
+                        is_leaf=_is_paged_leaf)
+
+
+def _with_row(cache, row, pos, axis: int):
+    """A layer's cache with this step's row written at ring slot
+    ``pos % cap`` of its token axis ``axis``.  A paged leaf's rows are
+    sliced first into a buffer of the layer's own, so the write is in
+    place and the gathered stack is not touched."""
+    if isinstance(cache, PagedLeaf):
+        cache = cache.rows()
+    slot = jnp.asarray(pos, jnp.int32) % cache.shape[axis]
+    start = (0,) * axis + (slot,) + (0,) * (cache.ndim - axis - 1)
+    return jax.lax.dynamic_update_slice(cache, row, start)
+
+
 def _ring_cache_from_prefill(entries: dict, S: int, cap: int) -> dict:
     """Place prefill entries at ring slots ``pos % cap`` so subsequent decode
     writes (slot = pos % cap) evict oldest-first; unfilled slots get the
@@ -232,16 +301,16 @@ def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
     """One-token decode. x: (B, 1, D); cache k/v: (B, cap, Hkv, hd).
 
     Writes the new k/v at ring slot ``pos % cap`` and attends over cached
-    absolute positions <= pos (within the sliding window, if any).
+    absolute positions <= pos (within the sliding window, if any).  Returns
+    the new cache, or for a paged cache (``PagedLeaf`` leaves) only the new
+    token's row of each leaf, which the caller writes into the pool.
     """
-    cap = cache["k"].shape[1]
     positions = jnp.full((1,), pos, jnp.int32)
     q, k_new, v_new = _project_qkv(cfg, p, x, positions)
-    slot = jnp.asarray(pos, jnp.int32) % cap
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
-    kv_pos = jax.lax.dynamic_update_slice(
-        cache["kv_pos"], positions, (slot,))
+    row = {"k": k_new, "v": v_new, "kv_pos": positions}
+    k = _with_row(cache["k"], k_new, pos, 1)
+    v = _with_row(cache["v"], v_new, pos, 1)
+    kv_pos = _with_row(cache["kv_pos"], positions, pos, 0)
     w = cfg.sliding_window if window is None else window
     if impl == "pallas":
         from repro.kernels.decode_attention import ops as da_ops
@@ -252,7 +321,8 @@ def gqa_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
         out = sdpa_naive(q, k, v, causal=True, window=w,
                          q_pos=positions, kv_pos=kv_pos,
                          softcap=cfg.attn_logit_softcap)
-    new_cache = {"k": k, "v": v, "kv_pos": kv_pos}
+    new_cache = (row if is_paged(cache)
+                 else {"k": k, "v": v, "kv_pos": kv_pos})
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"]), new_cache
 
 
@@ -381,17 +451,17 @@ def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
     ``absorb=False`` decompresses the whole cache each step (baseline);
     ``absorb=True`` runs attention in latent space (optimized): the query
     folded through ``wk_b`` meets the latent and the rope key in one
-    product over the whole latent width.
+    product over the whole latent width.  A paged cache returns only the
+    new token's rows, as ``gqa_decode``.
     """
     m = cfg.mla
     r = m.kv_lora_rank
-    cap = cache["lat"].shape[1]
     positions = jnp.full((1,), pos, jnp.int32)
     q_nope, q_pe = _mla_q(cfg, p, x, positions)          # (B,1,H,*)
     lat_new = _mla_latent(cfg, p, x, positions)
-    slot = jnp.asarray(pos, jnp.int32) % cap
-    lat = jax.lax.dynamic_update_slice(cache["lat"], lat_new, (0, slot, 0))
-    kv_pos = jax.lax.dynamic_update_slice(cache["kv_pos"], positions, (slot,))
+    row = {"lat": lat_new, "kv_pos": positions}
+    lat = _with_row(cache["lat"], lat_new, pos, 1)
+    kv_pos = _with_row(cache["kv_pos"], positions, pos, 0)
     scale = mla_scale(cfg)
     mask = jnp.where(kv_pos <= pos, 0.0, NEG_INF)[None, None, :]
 
@@ -413,7 +483,7 @@ def mla_decode(cfg: ModelConfig, p: dict, x: jax.Array, pos: jax.Array,
         s = s[:, :, 0, :] * scale + mask
         probs = jax.nn.softmax(s, axis=-1).astype(x.dtype)
         out = jnp.einsum("bhs,bshk->bhk", probs, v)[:, None]
-    new_cache = {"lat": lat, "kv_pos": kv_pos}
+    new_cache = row if is_paged(cache) else {"lat": lat, "kv_pos": kv_pos}
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"]), new_cache
 
 

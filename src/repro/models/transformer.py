@@ -243,9 +243,15 @@ def segment_apply(cfg: ModelConfig, p_stacked: dict, seg: Segment,
         x, caches_out = jax.lax.scan(body, x, p_stacked, unroll=unroll)
         return x, jnp.zeros((), jnp.float32), caches_out
 
-    # decode
+    # decode: the scan slices a dense cache stack layer by layer; for a
+    # paged view the scan hands each layer its index, the layer slices its
+    # own rows and the scan stacks only the new token's
+    paged = attn.is_paged(caches)
+
     def body(x, inp):
         rep_p, rep_cache = inp
+        if paged:
+            rep_cache = attn.at_layer(caches, rep_cache)
         caches_out = []
         for i, spec in enumerate(seg.blocks):
             x, _, c = block_apply(cfg, rep_p["blocks"][i], spec, x,
@@ -253,8 +259,8 @@ def segment_apply(cfg: ModelConfig, p_stacked: dict, seg: Segment,
             caches_out.append(c)
         return x, tuple(caches_out)
 
-    x, caches_out = jax.lax.scan(body, x, (p_stacked, caches),
-                                 unroll=unroll)
+    xs = jnp.arange(seg.count, dtype=jnp.int32) if paged else caches
+    x, caches_out = jax.lax.scan(body, x, (p_stacked, xs), unroll=unroll)
     return x, jnp.zeros((), jnp.float32), caches_out
 
 

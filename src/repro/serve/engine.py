@@ -13,8 +13,9 @@ KV memory comes in two modes:
 
 * **paged** (default) — device KV memory is a ``BlockPool`` of fixed-size
   pages shared by every lane.  A per-lane *block table* row maps logical
-  page index -> physical page; the vmapped decode step gathers each lane's
-  cache through its row and scatters back only the page it wrote.  Lanes
+  page index -> physical page; the vmapped decode step reads each lane's
+  cache in place through its row, each layer its own rows, and writes back
+  only the new token's row.  Lanes
   hold pages at token granularity: prompt pages at admission, one more
   page whenever decode crosses a page boundary, all freed the moment the
   request retires.  Admission is therefore **memory-based** — admit while
@@ -97,9 +98,9 @@ from repro.serve.kvcache import (BlockPool, _is_pos_leaf,
                                  extract_written_page, gather_lane_cache,
                                  init_caches_from_specs, install_pool_pages,
                                  latent_bytes_per_token, merged_pool_leaves,
-                                 pool_specs_from_lane_cache, scatter_pages,
-                                 scatter_prefill, scrub_pages,
-                                 token_axes_from_lengths)
+                                 paged_lane_cache, pool_specs_from_lane_cache,
+                                 scatter_pages, scatter_prefill, scatter_rows,
+                                 scrub_pages, token_axes_from_lengths)
 from repro.serve.prefix_cache import PrefixCache
 
 # Canonical per-request serving metrics (one schema across planes).
@@ -126,6 +127,10 @@ M_KV_MERGED_BYTES = "kv_pool_merged_bytes"
 M_MOE_HELD = "moe_experts_held"
 M_MOE_ROUTED = "moe_experts_routed"
 M_KV_LATENT_BYTES = "kv_latent_bytes_per_token"
+# decode launches by how they read the KV pool: in place (paged decode_step
+# and decode_multi) or through a gathered dense lane cache (verify_step)
+M_DECODE_IN_PLACE = "engine_decode_in_place_total"
+M_DECODE_GATHERED = "engine_decode_gathered_total"
 
 
 @dataclass(frozen=True)
@@ -472,6 +477,11 @@ class ContinuousBatchingEngine:
                                                    service=service)
         self._c_preemptions = self.registry.counter(M_PREEMPTIONS,
                                                     service=service)
+        # decode launches of this step, published once its wall time is
+        # taken (host_us_per_token does not count the publishing)
+        self._c_decode = {m: self.registry.counter(m, service=service)
+                          for m in (M_DECODE_IN_PLACE, M_DECODE_GATHERED)}
+        self._decode_launches = dict.fromkeys(self._c_decode, 0)
         if publish_gauges:
             self._g_queue = self.registry.gauge(
                 M_QUEUE_DEPTH, service=service, engine=engine_id)
@@ -625,36 +635,42 @@ class ContinuousBatchingEngine:
             return (jnp.zeros((B, 1), jnp.int32), jnp.zeros((B,), jnp.int32),
                     init_caches_from_specs(pool_abs))
 
-        def decode_step(params, toks, pos, bt, pool):
-            def lane(tok, p, bt_row):
-                caches = gather_lane_cache(pool, bt_row, lane_abs,
-                                           token_axes, page_size=ps)
-                logits, new_cache = bundle.decode_fn(params, tok, p, caches)
-                new_tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                active = bt_row[0] >= 0
-                lp = (p % (max_blocks * ps)) // ps
-                pages = extract_written_page(new_cache, lp, token_axes,
-                                             page_size=ps)
-                # the bt_row[lp] >= 0 guard drops writes landing past the
-                # lane's mapped span — a pipelined lane awaiting its final
-                # commit keeps decoding (garbage, never committed) and may
-                # walk onto a page that was never appended
-                phys = jnp.where(active & (bt_row[lp] >= 0), bt_row[lp],
-                                 jnp.int32(NP))
-                new_p = jnp.where(active, p + jnp.int32(1), p)
-                return new_tok, new_p, pages, phys
+        cap = max_blocks * ps
 
-            toks2, pos2, pages, phys = jax.vmap(
-                lane, in_axes=(0, 0, 0))(toks, pos, bt)
-            return toks2, pos2, scatter_pages(pool, phys, pages)
+        def decode_lanes(params, toks, pos, bt, pool, step=0):
+            """One greedy step of every lane at ``pos + step``, its cache
+            read in place from the pool: the next tokens, and the pool with
+            each lane's new row written.  The row is dropped for an
+            inactive lane, past the block row's end (a fused span's
+            masked tail) and on an unmapped page: a pipelined lane
+            awaiting its final commit keeps decoding (garbage, never
+            committed) and may walk onto a page that was never appended."""
+            def lane(tok, p, bt_row):
+                caches = paged_lane_cache(pool, bt_row, lane_abs,
+                                          token_axes, page_size=ps)
+                logits, rows = bundle.decode_fn(params, tok, p + step,
+                                                caches)
+                s = p % cap + step
+                lp = jnp.minimum(s // ps, max_blocks - 1)
+                ok = (bt_row[0] >= 0) & (s < cap) & (bt_row[lp] >= 0)
+                phys = jnp.where(ok, bt_row[lp], jnp.int32(NP))
+                return (jnp.argmax(logits, -1).astype(jnp.int32), rows,
+                        phys, s % ps)
+
+            nxt, rows, phys, offs = jax.vmap(lane)(toks, pos, bt)
+            return nxt, scatter_rows(pool, phys, offs, rows, token_axes)
+
+        def decode_step(params, toks, pos, bt, pool):
+            toks2, pool = decode_lanes(params, toks, pos, bt, pool)
+            return toks2, jnp.where(bt[:, 0] >= 0, pos + 1, pos), pool
 
         # fused multi-step decode: fuse_steps greedy steps per EXECUTE in
-        # one on-device fori_loop.  Per-lane ``lim`` (a const arg — the
-        # signature cache keys shapes, not values) masks token/pos updates
-        # once a lane hits its limit; cache writes past the mask land at
-        # positions every future query masks out (the same rejected-tail
-        # argument as speculative decode) and unmapped span pages are
-        # dropped by the scatter, so no masking of the KV write is needed.
+        # one on-device fori_loop, each the single step above.  Per-lane
+        # ``lim`` (a const arg — the signature cache keys shapes, not
+        # values) masks token/pos updates once a lane hits its limit; cache
+        # writes past the mask land at positions every future query masks
+        # out (the same rejected-tail argument as speculative decode), so
+        # no masking of the KV write is needed.
         kf = self.fuse_steps
         eos = self.eos_id
 
@@ -663,59 +679,35 @@ class ContinuousBatchingEngine:
             # const arg, all-sentinel when clean): in the steady state
             # the delta costs zero extra FIFO ops
             bt = apply_block_table_delta(bt, delta)
-            n_span = (kf - 1) // ps + 2
+            on = bt[:, 0] >= 0
+            lims = jnp.clip(lims, 0, kf)
+            # on-device stop-token detection: EOS folds into the same
+            # per-lane mask as the limit, so a lane freezes mid-span — its
+            # token stops updating, its position stops advancing, and
+            # post-EOS cache writes land at masked-out positions (the
+            # rejected-tail argument above).  Entering a span whose input
+            # token is already EOS keeps the lane frozen across EXECUTEs.
+            done0 = (toks[:, 0] == jnp.int32(eos)) if eos is not None \
+                else jnp.zeros(on.shape, bool)
 
-            def lane(tok, p, bt_row, lim):
-                cache = gather_lane_cache(pool, bt_row, lane_abs,
-                                          token_axes, page_size=ps)
-                on = bt_row[0] >= 0
-                lim = jnp.clip(lim, 0, kf)
-                # on-device stop-token detection: EOS folds into the same
-                # per-lane mask as the limit, so a lane freezes mid-span —
-                # its token stops updating, its position stops advancing,
-                # and post-EOS cache writes land at masked-out positions
-                # (the rejected-tail argument above).  Entering a span
-                # whose input token is already EOS keeps the lane frozen
-                # across EXECUTEs.
-                done0 = (tok[0] == jnp.int32(eos)) if eos is not None \
-                    else jnp.bool_(False)
+            def body(i, carry):
+                cur, outs, pool, adv, done = carry
+                nxt, pool = decode_lanes(params, cur, pos, bt, pool, i)
+                step_on = on & (i < lims) & ~done
+                cur2 = jnp.where(step_on[:, None], nxt, cur)
+                if eos is not None:
+                    done = done | (step_on & (cur2[:, 0] == jnp.int32(eos)))
+                adv2 = adv + step_on.astype(jnp.int32)
+                return cur2, outs.at[:, i].set(cur2[:, 0]), pool, adv2, done
 
-                def body(i, carry):
-                    cur, outs, c, adv, done = carry
-                    logits, c2 = bundle.decode_fn(params, cur, p + i, c)
-                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                    step_on = on & (i < lim) & ~done
-                    cur2 = jnp.where(step_on, nxt, cur)
-                    if eos is not None:
-                        done = done | (step_on & (cur2[0] == jnp.int32(eos)))
-                    adv2 = adv + step_on.astype(jnp.int32)
-                    return cur2, outs.at[i].set(cur2[0]), c2, adv2, done
-
-                cur, outs, cache, adv, _ = jax.lax.fori_loop(
-                    0, kf, body,
-                    (tok, jnp.zeros((kf,), jnp.int32), cache,
-                     jnp.int32(0), done0))
-                lp0 = (p % (max_blocks * ps)) // ps
-                pages, phys = [], []
-                for j in range(n_span):
-                    lp = jnp.minimum(lp0 + jnp.int32(j),
-                                     jnp.int32(max_blocks - 1))
-                    pages.append(extract_written_page(
-                        cache, lp, token_axes, page_size=ps))
-                    ok = on & (lp0 + j < max_blocks) & (bt_row[lp] >= 0)
-                    phys.append(jnp.where(ok, bt_row[lp], jnp.int32(NP)))
-                # adv == lim for active un-frozen lanes; a frozen lane's
-                # device position stops at EOS so the host rollback at
-                # commit time keeps both sides in lockstep
-                new_p = jnp.where(on, p + adv, p)
-                return cur, new_p, outs, tuple(pages), jnp.stack(phys)
-
-            toks2, pos2, outs, pages, phys = jax.vmap(
-                lane, in_axes=(0, 0, 0, 0))(toks, pos, bt, lims)
-            n_span = (kf - 1) // ps + 2
-            for j in range(n_span):
-                pool = scatter_pages(pool, phys[:, j], pages[j])
-            return outs, toks2, pos2, bt, pool
+            cur, outs, pool, adv, _ = jax.lax.fori_loop(
+                0, kf, body,
+                (toks, jnp.zeros((B, kf), jnp.int32), pool,
+                 jnp.zeros(on.shape, jnp.int32), done0))
+            # adv == lim for active un-frozen lanes; a frozen lane's device
+            # position stops at EOS so the host rollback at commit time
+            # keeps both sides in lockstep
+            return outs, cur, jnp.where(on, pos + adv, pos), bt, pool
 
         def bt_update(bt, delta):
             return apply_block_table_delta(bt, delta)
@@ -1944,6 +1936,7 @@ class ContinuousBatchingEngine:
                     pid = int(self._bt_host[st.slot, lp])
                     if pid >= 0:
                         dirty.add(pid)
+            self._decode_launches[M_DECODE_IN_PLACE] += 1
             if kf > 1:
                 exec_c = self._exec(
                     "decode_multi",
@@ -2150,6 +2143,7 @@ class ContinuousBatchingEngine:
                 pid = int(self._bt_host[st.slot, lp])
                 if pid >= 0:
                     dirty.add(pid)
+        self._decode_launches[M_DECODE_GATHERED] += 1
         self._exec(
             f"verify_step_k{k}",
             ("params", "toks", f"draft_toks_k{k}", "pos", "block_table",
@@ -2334,6 +2328,7 @@ class ContinuousBatchingEngine:
                     decoded += self._fused_iteration()
             elif self._active:
                 if self.paged:
+                    self._decode_launches[M_DECODE_IN_PLACE] += 1
                     self._flush_block_table()
                     dirty = sorted({int(self._bt_host[
                         s.slot, s.pos // self.page_size])
@@ -2371,6 +2366,10 @@ class ContinuousBatchingEngine:
         # -- host/device attribution: wall minus the monitor-measured
         #    device phases is host overhead (batching, commit, paging)
         wall = time.perf_counter() - t_step0
+        for m, n in self._decode_launches.items():
+            if n:
+                self._c_decode[m].inc(n)
+                self._decode_launches[m] = 0
         device_s = queue_wait_s = 0.0
         execs = 0
         carry: List = []

@@ -12,13 +12,15 @@ virtualizes that memory behind an indirection layer, PagedAttention-style:
   exceed what worst-case reservations would allow.
 * **block table** — per-lane ``(max_blocks,)`` int32 rows mapping logical
   page index -> physical page id (-1 = unmapped).  The vmapped decode step
-  gathers each lane's logical cache through its row; admission scatters the
-  prefill cache into freshly allocated pages.
-* traced helpers (``gather_lane_cache`` / ``extract_written_page`` /
-  ``scatter_pages`` / ``scatter_prefill`` / ``scrub_pages`` /
-  ``compact_pool``) — the kernel-side pieces the engine's programs are
-  built from.  ``scrub_pages`` invalidates the position row of every page
-  on (re)allocation, the paged analogue of the monitor zeroing freed device
+  hands the model a paged view of each lane's cache through its row (each
+  layer reads its own rows of the pool) and writes back one row per lane;
+  admission scatters the prefill cache into freshly allocated pages.
+* traced helpers (``paged_lane_cache`` / ``scatter_rows`` /
+  ``gather_lane_cache`` / ``extract_written_page`` / ``scatter_pages`` /
+  ``scatter_prefill`` / ``scrub_pages`` / ``compact_pool``) — the
+  kernel-side pieces the engine's programs are built from.
+  ``scrub_pages`` invalidates the position row of every page on
+  (re)allocation, the paged analogue of the monitor zeroing freed device
   memory (§3.4 isolation): a new owner can never attend to a previous
   lane's tokens.
 * ``BlockPool.compact`` — defragmentation: pack used pages into the lowest
@@ -43,7 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.state import tree_bytes
-from repro.models.attention import _INVALID_POS
+from repro.models.attention import _INVALID_POS, PagedLeaf
 
 # one exported byte-accounting helper (shared with the buffer state machine)
 cache_bytes = tree_bytes
@@ -304,11 +306,11 @@ def _stored_rest(path, rest):
     return rest[:-2] + (rest[-2] * rest[-1],)
 
 
-def _to_pool(vals, pool_leaf):
-    """Whole pages ``(n, page_size, *per-token)``, in a lane cache's
-    per-token shape or the pool's -> ``(n, *pool_leaf.shape[1:])``, the
-    stored axis zero-padded where the pool pads it."""
-    tail = pool_leaf.shape[1:]
+def _to_pool(vals, tail):
+    """Whole pages ``(n, page_size, *per-token)`` or single rows ``(n,
+    *per-token)``, in a lane cache's per-token shape or the pool's -> ``(n,
+    *tail)``, ``tail`` the pool leaf's shape past its page (or page and
+    row) axes, the stored axis zero-padded where the pool pads it."""
     width = vals.size // (vals.shape[0] * math.prod(tail[:-1]))
     vals = vals.reshape(vals.shape[:1] + tail[:-1] + (width,))
     if width < tail[-1]:
@@ -353,42 +355,87 @@ def latent_bytes_per_token(lane_cache_abs) -> int:
 # ---------------------------------------------------------------------------
 # Traced kernel-side helpers
 # ---------------------------------------------------------------------------
+def _gather_rows(path, leaf, block_row, *, page_size: int,
+                 whole_pages: bool = False):
+    """A pool leaf's rows of one lane's token slots, token first: ``(cap,
+    *stored)``.  Unmapped pages (id < 0) are clamped for the gather but
+    their positions are forced to the INVALID sentinel, so attention masks
+    them out no matter what the clamped page holds.
+
+    Rows are gathered one token at a time: a page of a wide model (1.3 MB
+    for stablelm-3b) makes the TPU gather in slices of the merged axis and
+    stitch them back (a 40.6 ms decode step against 38.0 ms, v5e).
+    ``whole_pages`` gathers pages instead, for narrow rows, where the
+    chip's gather pays for every index."""
+    safe = jnp.clip(block_row, 0, leaf.shape[0] - 1)
+    stored = leaf.shape[2:]
+    if whole_pages:
+        got = leaf[safe].reshape((-1,) + stored)
+    else:
+        rows = (safe[:, None] * page_size
+                + jnp.arange(page_size, dtype=safe.dtype)).reshape(-1)
+        got = leaf.reshape((-1,) + stored)[rows]
+    if _is_pos_leaf(path):
+        valid = jnp.repeat(block_row >= 0, page_size)
+        got = jnp.where(valid.reshape((-1,) + (1,) * (got.ndim - 1)),
+                        got, _INVALID_POS)
+    return got
+
+
 def gather_lane_cache(pool, block_row, lane_cache_abs, token_axes, *,
                       page_size: int):
     """Reassemble one lane's logical cache from the pool through its block
     table row (traced, vmapped over lanes by the engine).  The lane cache
     the pool was built from gives each leaf's per-token shape, merged
-    axes split back.
-
-    Unmapped pages (id < 0) are clamped for the gather but their positions
-    are forced to the INVALID sentinel, so attention masks them out no
-    matter what the clamped page holds.
-    """
-    max_blocks = block_row.shape[0]
-    cap = max_blocks * page_size
+    axes split back and the lane padding cut."""
+    cap = block_row.shape[0] * page_size
 
     def gk(path, leaf, lane, axis):
-        safe = jnp.clip(block_row, 0, leaf.shape[0] - 1)
-        # gather token rows, not whole pages: a page of a wide model (1.3 MB
-        # for stablelm-3b) makes the TPU gather in slices of the merged axis
-        # and stitch them back (a 40.6 ms decode step against 38.0 ms, v5e)
-        rows = (safe[:, None] * page_size
-                + jnp.arange(page_size, dtype=safe.dtype)).reshape(cap)
-        flat = leaf.reshape((-1,) + leaf.shape[2:])[rows]
+        flat = _gather_rows(path, leaf, block_row, page_size=page_size)
         rest = _lane_rest(lane, axis)
         width = math.prod(rest) // math.prod(flat.shape[1:-1])
         if width < flat.shape[-1]:               # the pool's lane padding
             flat = flat[..., :width]
-        flat = flat.reshape((cap,) + rest)
-        if _is_pos_leaf(path):
-            valid = jnp.repeat(block_row >= 0, page_size)
-            flat = jnp.where(
-                valid.reshape((cap,) + (1,) * (flat.ndim - 1)),
-                flat, _INVALID_POS)
-        return jnp.moveaxis(flat, 0, axis)       # original lane layout
+        return jnp.moveaxis(flat.reshape((cap,) + rest), 0, axis)
 
     return jax.tree_util.tree_map_with_path(gk, pool, lane_cache_abs,
                                             token_axes)
+
+
+def paged_lane_cache(pool, block_row, lane_cache_abs, token_axes, *,
+                     page_size: int):
+    """A lane's cache as the model's paged decode reads it (traced, vmapped
+    over lanes by the engine): each leaf a ``PagedLeaf`` over the lane's
+    rows of every layer, gathered once, token first and in the pool's
+    merged layout, for the layer scan to slice.  The model returns only
+    the new token's rows, which ``scatter_rows`` writes.  An MLA latent
+    and the positions are gathered in whole pages: their rows are
+    narrow."""
+    cap = block_row.shape[0] * page_size
+
+    def view(path, leaf, lane, axis):
+        flat = _leaf_name(path) == "lat"
+        stack = _gather_rows(path, leaf, block_row, page_size=page_size,
+                             whole_pages=flat or _is_pos_leaf(path))
+        # the layer scan's stack leads the lane cache: one layer's leaf is
+        # the rest, its token axis one to the left
+        shape = lane.shape[1:axis] + (cap,) + lane.shape[axis + 1:]
+        return PagedLeaf(stack, shape, axis - 1, flat)
+
+    return jax.tree_util.tree_map_with_path(view, pool, lane_cache_abs,
+                                            token_axes)
+
+
+def scatter_rows(pool, phys_ids, offsets, rows, token_axes):
+    """Write each lane's row of one decode step into the pool at page
+    ``phys_ids`` (lanes,), row ``offsets`` (lanes,) of that page.  ``rows``
+    is the lane cache with a token axis of one, lanes leading; out-of-range
+    pages (inactive lanes, rows past a lane's mapped span) are dropped."""
+    def wr(pool_leaf, row, axis):
+        vals = _to_pool(jnp.squeeze(row, axis + 1), pool_leaf.shape[2:])
+        return pool_leaf.at[phys_ids, offsets].set(vals, mode="drop")
+
+    return jax.tree.map(wr, pool, rows, token_axes)
 
 
 def extract_written_page(new_lane_cache, logical_page, token_axes, *,
@@ -410,7 +457,8 @@ def scatter_pages(pool, phys_ids, pages):
     out-of-range ids (inactive lanes) are dropped.  Active lanes own
     disjoint pages, so the scatter is conflict-free."""
     return jax.tree.map(
-        lambda pl, pg: pl.at[phys_ids].set(_to_pool(pg, pl), mode="drop"),
+        lambda pl, pg: pl.at[phys_ids].set(_to_pool(pg, pl.shape[1:]),
+                                           mode="drop"),
         pool, pages)
 
 
@@ -432,7 +480,7 @@ def scatter_prefill(pool, page_ids, pf_cache, token_axes, *,
                     else jnp.zeros((pad,) + vals.shape[1:], vals.dtype))
             vals = jnp.concatenate([vals, fill])
         vals = _to_pool(vals.reshape((n_pp, page_size) + vals.shape[1:]),
-                        pool_leaf)
+                        pool_leaf.shape[1:])
         return pool_leaf.at[page_ids].set(vals)
 
     return jax.tree_util.tree_map_with_path(sc, pool, pf_cache, token_axes)

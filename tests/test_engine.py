@@ -12,8 +12,9 @@ from repro.core import FunkyCL, Monitor, SliceAllocator
 from repro.scaling.autoscaler import M_KV_FREE_PAGES
 from repro.scaling.metrics import MetricsRegistry
 from repro.scaling.serving import RequestRouter
-from repro.serve.engine import (M_TBT, M_TTFT, ContinuousBatchingEngine,
-                                ServeRequest)
+from repro.serve.engine import (M_DECODE_GATHERED, M_DECODE_IN_PLACE, M_TBT,
+                                M_TTFT, ContinuousBatchingEngine, ServeRequest,
+                                SpecConfig)
 
 ARCH = "yi-9b-smoke"
 PROMPT_LEN = 8
@@ -104,6 +105,34 @@ def test_decode_and_admit_are_donated(engine_run):
     assert ("decode_step", (1, 2, 4)) in mon_keys          # toks, pos, pool
     assert (f"prefill_admit_{PROMPT_LEN}", (1, 2, 3)) in mon_keys
     assert ("scrub", (0,)) in mon_keys
+
+
+@pytest.mark.parametrize("mode", ["plain", "fused", "spec"])
+def test_decode_launches_counted_by_how_they_read_the_pool(mode):
+    """Every paged decode launch (``decode_step``, ``decode_multi``) reads
+    the pool in place; speculative verify still gathers each lane's dense
+    cache.  The two counters say how many launches took each way."""
+    kw = {"plain": {}, "fused": dict(fuse_steps=4, async_depth=1),
+          "spec": dict(spec=SpecConfig(k=2))}[mode]
+    mon, eng, reg = make_engine(slots=2, max_new=8, **kw)
+    launched = []
+    execute = eng._exec
+
+    def counting(name, *a, **k):
+        launched.append(name)
+        return execute(name, *a, **k)
+
+    eng._exec = counting
+    for r in make_requests([5, 8, 3]):
+        eng.submit(r)
+    eng.run_until_drained()
+    mon.vfpga_exit()
+    in_place = sum(n in ("decode_step", "decode_multi") for n in launched)
+    gathered = sum(n.startswith("verify_step") for n in launched)
+    assert in_place + gathered > 0
+    assert reg.counter(M_DECODE_IN_PLACE, service="svc").value == in_place
+    assert reg.counter(M_DECODE_GATHERED, service="svc").value == gathered
+    assert (gathered > 0) == (mode == "spec")
 
 
 SPEC = [3, 6, 4, 5]

@@ -187,3 +187,82 @@ def test_paged_pool_is_page_major_for_v5e(model, one_chip):
         hlo = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile().as_text()
         for leaf in stored:
             assert not _whole_leaf_copies(hlo, leaf), (prog, leaf.shape)
+
+
+class _Programs:
+    """A stand-in for the guest's device API that keeps each program."""
+    _monitor = None
+
+    def __init__(self):
+        self.progs = {}
+
+    def clCreateProgramWithBinary(self, program, abstracts, donate_argnums=()):
+        self.progs[program.program_id] = (program.fn, abstracts,
+                                          donate_argnums)
+
+
+# the cells' engines: (slots, prompt buckets, max_new_tokens), page 8
+DECODE_CELLS = {"stablelm-3b": (8, (128, 512), 64),
+                "yi-9b-24l": (8, (128, 512), 64),
+                "deepseek-v3-ep32-5l": (64, (256, 1024), 1536)}
+# temporaries of the compiled decode_step that gathered each lane's whole
+# cache (CPU compile for v5e, the parent of the paged decode in place)
+DENSE_DECODE_TEMP = {"stablelm-3b": 4_097_519_104, "yi-9b-24l": 456_476_160,
+                     "deepseek-v3-ep32-5l": 2_491_664_384}
+
+
+@pytest.mark.parametrize("model", sorted(DECODE_CELLS))
+def test_paged_decode_moves_no_lane_cache_for_v5e(model, one_chip,
+                                                  monkeypatch):
+    """The cells' ``decode_step`` reads the pool in place: its entry
+    computation moves a dense lane-cache stack (every lane's cache of one
+    leaf at ``max_ctx``) at most once per k/v leaf, the chip's reorder of
+    the gathered rows to layer-major, and never through a relayout
+    (``reshape``) or a cache-writing fusion, where the step that gathered
+    each lane's dense cache made two to four such moves per leaf besides
+    the layer scan's copies and cache rewrites.  Its temporaries stay
+    below that step's (stablelm-3b 4,097,519,104 B, yi-9b-24l 456,476,160
+    B, deepseek-v3-ep32-5l 2,491,664,384 B)."""
+    import dataclasses
+
+    from repro.configs import registry
+    from repro.scaling.metrics import MetricsRegistry
+    from repro.serve.engine import ContinuousBatchingEngine
+
+    if model == "yi-9b-24l":       # one of Yi-9B's two pipeline stages
+        monkeypatch.setitem(registry.SHARES, model, dataclasses.replace(
+            get_arch("yi-9b"), name=model, num_layers=24))
+    slots, buckets, max_new = DECODE_CELLS[model]
+    cl = _Programs()
+    eng = ContinuousBatchingEngine(
+        model, cl, slots=slots, prompt_len=max(buckets),
+        prompt_buckets=buckets, max_new_tokens=max_new, page_size=PAGE,
+        registry=MetricsRegistry())
+    eng.setup(restore=True)
+    fn, abstracts, donate = cl.progs["decode_step"]
+    args = jax.tree.map(lambda l: jax.ShapeDtypeStruct(
+        l.shape, l.dtype, sharding=one_chip), abstracts)
+    compiled = jax.jit(fn, donate_argnums=donate,
+                       out_shardings=one_chip).lower(*args).compile()
+    lane = jax.eval_shape(eng._prefill_fn(), abstracts[0], jax.ShapeDtypeStruct(
+        (1, eng.prompt_len), I32))[1]
+    leaves = _stored_leaves(lane)
+    stacks = [slots * leaf.size // leaf.shape[axis] * eng.max_ctx
+              for leaf, axis in zip(leaves, _stored_leaves(eng._token_axes))]
+    hlo = compiled.as_text()
+    entry = hlo[hlo.index("\nENTRY"):]
+    moves = []
+    for m in re.finditer(r"%([\w.-]+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(",
+                         entry):
+        name, dims, op = m.group(1), m.group(3), m.group(4)
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if n >= min(stacks) and (
+                op in ("copy", "transpose", "reshape") or op == "fusion"
+                and re.search(r"copy|transpose|reshape|dynamic-update-slice",
+                              name)):
+            moves.append((op, name))
+    kv_leaves = sum(leaf.ndim > 4 for leaf in leaves)      # not the latent
+    assert len(moves) <= kv_leaves, moves
+    assert all(op == "copy" for op, _ in moves), moves
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < DENSE_DECODE_TEMP[model], temp
